@@ -490,7 +490,7 @@ def chaos_schedule(seed, n, kills, target):
     row_w = row_width(n)
     plans = {}
     for attempt in range(kills):
-        rng = random.Random((seed, attempt))
+        rng = random.Random(f"{seed}:{attempt}")
         plan = FaultPlan(seed)
         victim = rng.randrange(n)
         if attempt == 1 and kills > 1:
@@ -756,7 +756,7 @@ def elastic_main(args, transport, specs):
     # (strictly after the first cadence commit), attempt 1 runs at the
     # surviving n1 and loses one more, attempt 2 grows back to n0 when
     # capacity "returns" and finishes the horizon fault-free
-    rng = random.Random((seed, "elastic"))
+    rng = random.Random(f"{seed}:elastic")
     step0 = every + 2
     plan0 = FaultPlan(seed)
     victims0 = sorted(rng.sample(range(n0), kills))

@@ -22,7 +22,7 @@ Per-array encodings are a pluggable `ImageCodec` STACK
 the previous checkpoint for slowly-changing state), `RawCodec` is the
 terminal fallback, and every payload chunk is stamped with a Fletcher
 digest that restore verifies (`use_pallas=True` routes digests and
-deltas through the pallas kernels; the numpy oracles are the fallback).
+deltas through the pallas kernels, otherwise the numpy oracles run).
 Delta chains are bounded: a full image every `full_every` checkpoints
 on the write side, a `max_chain` reconstruction bound on the read side,
 and GC protects the transitive base chain of every kept checkpoint.
@@ -207,6 +207,10 @@ class CheckpointManager:
         if self._pending is not None:
             self._pending.result()
             self._pending = None
+
+    def writing(self) -> bool:
+        """Whether a background write is still in flight."""
+        return self._pending is not None and not self._pending.done()
 
     def steps(self) -> List[int]:
         out = []

@@ -28,12 +28,11 @@ Two consumers share this module:
     typed `ImageIntegrityError`, never a garbage restore (and never a
     raw struct/zlib traceback).
 
-All heavy per-byte work (XOR delta, digest, int8 quantization) routes
-through the pallas kernel packages' host entry points
-(`delta_host` / `checksum_host` / `quantize_host`), each of which falls
-back to its numpy oracle when the kernel path is unavailable — the
-checkpoint pipeline never depends on the accelerator stack being
-healthy.
+All heavy per-byte work (XOR delta, digest, int8 quantization) runs
+either through the numpy oracles (the default) or, with
+`use_pallas=True`, through the pallas kernel packages' host entry
+points (`delta_host` / `checksum_host` / `quantize_host`).  A kernel
+failure on that path raises: it never falls back to the oracle.
 """
 from __future__ import annotations
 
@@ -54,27 +53,21 @@ from repro.kernels.quantize import ref as quant_ref
 # from a jax-free process (socket rank processes fork per checkpoint —
 # a jax-sized address space would dominate the fork cost), so the
 # kernel paths are imported lazily and only when use_pallas is asked
-# for, with the numpy oracles as the always-available fallback.
+# for.
 
 
 def _delta_dispatch(cur: np.ndarray, prev: np.ndarray,
                     use_pallas: bool) -> np.ndarray:
     if use_pallas:
-        try:
-            from repro.kernels.delta.ops import delta_host
-            return delta_host(cur, prev, use_pallas=True)
-        except Exception:  # noqa: BLE001 — oracle fallback by design
-            pass
+        from repro.kernels.delta.ops import delta_host
+        return delta_host(cur, prev, use_pallas=True)
     return delta_np(cur, prev)
 
 
 def _quantize_dispatch(x: np.ndarray, use_pallas: bool):
     if use_pallas:
-        try:
-            from repro.kernels.quantize.ops import quantize_host
-            return quantize_host(x, use_pallas=True)
-        except Exception:  # noqa: BLE001 — oracle fallback by design
-            pass
+        from repro.kernels.quantize.ops import quantize_host
+        return quantize_host(x, use_pallas=True)
     return quant_ref.quantize_np(x)
 
 
@@ -181,7 +174,7 @@ class RawCodec(ImageCodec):
 
 class QuantizeCodec(ImageCodec):
     """Blockwise-int8 low-precision shadow (pallas quantize kernel with
-    numpy oracle fallback).  Lossy by design — selected for state that
+    `use_pallas`, else the numpy oracle).  Lossy by design — selected for state that
     tolerates it (optimizer moments)."""
 
     name = "int8_block"
@@ -202,7 +195,7 @@ class QuantizeCodec(ImageCodec):
 
 class DeltaCodec(ImageCodec):
     """XOR delta against the same array in the base image (pallas delta
-    kernel with numpy oracle fallback).  Exact for every dtype; claims a
+    kernel with `use_pallas`, else the numpy oracle).  Exact for every dtype; claims a
     path only when the manager's chain policy allows another delta AND
     the base image holds a shape/dtype-compatible array."""
 
@@ -232,12 +225,8 @@ class DeltaCodec(ImageCodec):
 def shard_digest(data: bytes, use_pallas: bool = False) -> int:
     """Fletcher digest of one payload chunk (write AND restore path)."""
     if use_pallas:
-        try:
-            from repro.kernels.checksum.ops import checksum_host
-            return checksum_host(np.frombuffer(data, np.uint8),
-                                 use_pallas=True)
-        except Exception:  # noqa: BLE001 — oracle fallback by design
-            pass
+        from repro.kernels.checksum.ops import checksum_host
+        return checksum_host(np.frombuffer(data, np.uint8), use_pallas=True)
     return checksum_np(np.frombuffer(data, np.uint8))
 
 
@@ -454,7 +443,7 @@ class SnapshotCodec:
     compressed+digested stream.
 
     A delta blob encodes each array as an XOR against the base snapshot
-    (pallas kernel w/ oracle fallback) — unchanged regions are zero
+    (pallas kernel with `use_pallas`, else numpy) — unchanged regions are zero
     runs, so small-change steps produce small images.  Every cell runs
     through the byte-shuffle filter, then deflate at `compress_level`.
     Arrays absent from the base (or with changed shape/dtype) degrade

@@ -127,15 +127,20 @@ class MANARuntime:
 
     # ---- lifecycle -----------------------------------------------------------
     def initialize(self) -> None:
-        self.state = init_train_state(self.cfg, self.rc,
-                                      jax.random.PRNGKey(self.seed))
-        if self.lower.mesh is not None:
-            from jax.sharding import NamedSharding
-            self.state = jax.tree.map(
-                lambda x, sp: jax.device_put(
-                    x, NamedSharding(self.lower.mesh, sp)),
-                self.state, self.lower.state_specs,
-                is_leaf=lambda x: not isinstance(x, dict))
+        key = jax.random.PRNGKey(self.seed)
+        if self.lower.mesh is None:
+            self.state = init_train_state(self.cfg, self.rc, key)
+            return
+        # built in place, shard by shard: a state larger than one
+        # device's memory never passes through one device whole
+        from jax.sharding import NamedSharding, PartitionSpec
+        shardings = jax.tree.map(
+            lambda sp: NamedSharding(self.lower.mesh, sp),
+            self.lower.state_specs,
+            is_leaf=lambda x: isinstance(x, PartitionSpec))
+        self.state = jax.jit(
+            lambda k: init_train_state(self.cfg, self.rc, k),
+            out_shardings=shardings)(key)
 
     def restore(self, step: Optional[int] = None) -> int:
         """Elastic restart: rebind the upper half onto THIS lower half

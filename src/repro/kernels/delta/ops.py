@@ -3,22 +3,23 @@ incremental checkpoint pipeline calls per shard."""
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import host_words
 from repro.kernels.delta import ref
 from repro.kernels.delta.delta import xor_pallas
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
-def delta(cur: jnp.ndarray, prev: jnp.ndarray, use_kernel: bool = True,
-          interpret: bool = True) -> jnp.ndarray:
-    a, b = ref.to_words(cur), ref.to_words(prev)
-    if use_kernel:
-        return xor_pallas(a, b, interpret=interpret)
-    return a ^ b
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def delta_words(a: jnp.ndarray, b: jnp.ndarray,
+                interpret: Optional[bool] = None) -> jnp.ndarray:
+    """XOR of two (n, DBLOCK) uint32 word streams (the kernel path of
+    `delta_host`, which builds the words on the host)."""
+    return xor_pallas(a, b, interpret=interpret)
 
 
 def delta_host(cur: np.ndarray, prev: np.ndarray,
@@ -28,15 +29,15 @@ def delta_host(cur: np.ndarray, prev: np.ndarray,
     With use_pallas the XOR runs through the Pallas word-tile kernel
     (the padded uint32 word stream is unpacked little-endian and
     trimmed back to the array's byte length — bit-exact with the numpy
-    oracle); any kernel failure falls back to `ref.delta_np`.
+    oracle) and a kernel failure raises; otherwise `ref.delta_np`
+    computes it.
     """
     if use_pallas:
-        try:
-            words = np.asarray(delta(jnp.asarray(cur), jnp.asarray(prev)))
-            raw = words.astype("<u4", copy=False).tobytes()
-            return np.frombuffer(raw[:cur.nbytes], np.uint8).copy()
-        except Exception:  # noqa: BLE001 — oracle fallback by design
-            pass
+        assert cur.nbytes == prev.nbytes, (cur.nbytes, prev.nbytes)
+        words = delta_words(jnp.asarray(host_words(cur, ref.DBLOCK)),
+                            jnp.asarray(host_words(prev, ref.DBLOCK)))
+        raw = np.asarray(words).astype("<u4", copy=False).reshape(-1)
+        return raw.view(np.uint8)[:cur.nbytes]
     return ref.delta_np(cur, prev)
 
 

@@ -214,8 +214,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         t_compile = time.monotonic() - t0 - t_lower
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax: one dict per program
-            cost = cost[0] if cost else {}
         hlo = compiled.as_text()
     from repro.launch.hlo_analysis import analyze_hlo
     colls = collective_bytes(hlo)
@@ -227,18 +225,10 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         lower_s=round(t_lower, 2),
         compile_s=round(t_compile, 2),
         memory={
-            "argument_bytes": getattr(mem, "argument_size_in_bytes", None),
-            "output_bytes": getattr(mem, "output_size_in_bytes", None),
-            "temp_bytes": getattr(mem, "temp_size_in_bytes", None),
-            # older jaxlib has no peak_memory_in_bytes; approximate the
-            # live-set peak as args + outputs + temporaries (attribute
-            # presence, not truthiness: a real measured 0 must survive)
-            "peak_bytes": (
-                mem.peak_memory_in_bytes
-                if hasattr(mem, "peak_memory_in_bytes")
-                else (getattr(mem, "argument_size_in_bytes", 0)
-                      + getattr(mem, "output_size_in_bytes", 0)
-                      + getattr(mem, "temp_size_in_bytes", 0))),
+            "argument_bytes": mem.argument_size_in_bytes,
+            "output_bytes": mem.output_size_in_bytes,
+            "temp_bytes": mem.temp_size_in_bytes,
+            "peak_bytes": mem.peak_memory_in_bytes,
         },
         cost={
             "flops": cost.get("flops"),

@@ -14,6 +14,7 @@ import os
 from repro.configs import ARCHS, SHAPES_BY_NAME, reduced_config
 from repro.configs.base import RunConfig, ShapeConfig
 from repro.core.runtime import MANARuntime
+from repro.launch.compile_cache import checkout_root, enable_compile_cache
 
 
 def main() -> None:
@@ -38,6 +39,7 @@ def main() -> None:
     ap.add_argument("--delta-params", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache(checkout_root())
 
     cfg = ARCHS[args.arch]
     if args.reduced:

@@ -161,5 +161,8 @@ def chunked_softmax_xent(h, head, labels, mask, chunk: int,
         loss = (lse - tgt) * mx
         return (carry[0] + loss.sum(), carry[1] + mx.sum()), None
 
-    (tot, cnt), _ = jax.lax.scan(body, (jnp.zeros(()), jnp.zeros(())), (hc, lc, mc))
+    # rematerialized: without it autodiff keeps every chunk's f32 logits
+    # and one-hot as scan residuals, i.e. the whole (B,S,V) tensor twice
+    (tot, cnt), _ = jax.lax.scan(jax.checkpoint(body),
+                                 (jnp.zeros(()), jnp.zeros(())), (hc, lc, mc))
     return tot, cnt

@@ -49,8 +49,5 @@ def test_hlo_analyzer_trip_counts():
         jax.ShapeDtypeStruct((12, 16, 16), jnp.float32)).compile()
     r = analyze_hlo(c.as_text())
     assert r["dot_flops"] == 12 * 2 * 8 * 16 * 16
-    cost = c.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # older jax: one dict per program
-        cost = cost[0]
-    raw = cost["flops"]
+    raw = c.cost_analysis()["flops"]
     assert raw < r["dot_flops"]  # the undercount being corrected
