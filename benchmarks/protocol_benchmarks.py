@@ -467,6 +467,7 @@ def _ckpt_pipeline_worker(n, shard_kb, steps, every, async_ckpt, mutate_frac,
 
     from repro.comm import collectives as coll
     from repro.comm.transport.harness import row_width
+    from repro.core import tracing
     from repro.core.codec import (ChainPolicy, IncrementalSnapshotter,
                                   snap_meta)
 
@@ -494,6 +495,15 @@ def _ckpt_pipeline_worker(n, shard_kb, steps, every, async_ckpt, mutate_frac,
             sizes.append((meta["encoding"], meta["payload_bytes"]))
             ctx.coord.ship_snapshot(a.ckpt_epoch, blob)
 
+        def safe_point():
+            # post-closure stall: the safe point less its phase-1 park
+            # (alignment skew, not protocol cost), from the program's
+            # own spans
+            with tracing.span("bench.safe_point") as sp:
+                took = a.safe_point(snapshot, timeout=sp_timeout)
+            if took:
+                stalls.append(sp.seconds - sp.total("park"))
+
         step = 0
         for step in range(steps):
             if r == 0 and step and step % every == 0:
@@ -504,15 +514,11 @@ def _ckpt_pipeline_worker(n, shard_kb, steps, every, async_ckpt, mutate_frac,
             # ranks, phase-1 alignment skew alone can pass 60s
             a.collective(a.row, coll.allreduce, 1, lambda x, y: x + y,
                          timeout=sp_timeout)
-            if a._ckpt_pending() and a.safe_point(snapshot,
-                                                 timeout=sp_timeout):
-                # post-closure stall: drain-barrier back to compute
-                # (agent-measured; excludes phase-1 alignment skew)
-                stalls.append(a.last_commit_stall_s)
+            if a._ckpt_pending():
+                safe_point()
         a.collective(a.world_comm, coll.barrier, timeout=sp_timeout)
         while a._ckpt_pending():
-            if a.safe_point(snapshot, timeout=sp_timeout):
-                stalls.append(a.last_commit_stall_s)
+            safe_point()
             time.sleep(0.002)
         a.drain_writer()
         return {"stalls": stalls, "sizes": sizes}

@@ -26,7 +26,6 @@ prints no result.  Checkpoints go to a temp directory, removed at exit.
 from __future__ import annotations
 
 import argparse
-import functools
 import gc
 import json
 import os
@@ -45,41 +44,12 @@ import jax  # noqa: E402
 
 from repro.configs import ARCHS  # noqa: E402
 from repro.configs.base import RunConfig, ShapeConfig  # noqa: E402
+from repro.core import tracing  # noqa: E402
 from repro.core.checkpoint import MANIFEST  # noqa: E402
 from repro.core.runtime import MANARuntime  # noqa: E402
 
 BATCH, SEQ = 8, 2048
 LOSS_RTOL_ACROSS_MESHES = 5e-3
-
-
-class CompileCounter:
-    """Counts backend compiles and persistent-cache hits/misses through
-    jax.monitoring (listeners stay registered for the process, so there
-    is one counter per process: `compile_counter()`)."""
-
-    def __init__(self):
-        self.compiles = 0
-        self.compile_s = 0.0
-        self.cache_hits = 0
-        self.cache_misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compiles += 1
-            self.compile_s += secs
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.cache_misses += 1
-
-
-@functools.cache
-def compile_counter() -> CompileCounter:
-    return CompileCounter()
 
 
 def run_config(cfg, batch: int = BATCH, seq: int = SEQ) -> RunConfig:
@@ -138,36 +108,36 @@ def train_and_save(cfg, rc, ckpt_dir, *, mesh=None, every=2, saves=2,
     safe point or the wait for its write.  A step is "clean" unless it
     compiled or began while a save's background write was still in
     flight; those are reported apart."""
-    counter = compile_counter()
-    rt = MANARuntime(cfg, rc, ckpt_dir=ckpt_dir, mesh=mesh,
-                     ckpt_every_steps=every, use_pallas=use_pallas,
-                     delta_params=delta_params, seed=seed)
-    rt.initialize()
-    jax.block_until_ready(rt.state)
-    spread = device_bytes(rt.state)
-    marks, stalls = [], []
-
-    def on_metrics(step, m):
+    with tracing.recording() as rec:
+        rt = MANARuntime(cfg, rc, ckpt_dir=ckpt_dir, mesh=mesh,
+                         ckpt_every_steps=every, use_pallas=use_pallas,
+                         delta_params=delta_params, seed=seed)
+        rt.initialize()
         jax.block_until_ready(rt.state)
-        marks.append((time.monotonic(), rt.checkpoints_taken,
-                      rt.ckpt.writing(), counter.compiles))
-        if rt.checkpoints_taken > len(stalls):
-            stalls.append(rt.agent.last_commit_stall_s)
+        spread = device_bytes(rt.state)
+        marks = []
 
-    t0, c0, s0 = time.monotonic(), counter.compiles, counter.compile_s
-    rt.run(1, on_metrics=on_metrics)
-    first_step_s = time.monotonic() - t0
-    compiles_first_step = counter.compiles - c0
-    compile_s = counter.compile_s - s0
-    c1 = counter.compiles
-    rt.run(every * saves - 1, on_metrics=on_metrics)
-    if rt.checkpoints_taken > len(stalls):
-        stalls.append(rt.agent.last_commit_stall_s)
-    compiles_in_saves = counter.compiles - c1
-    host = host_copy(rt.state)
-    segment = len(marks)
-    rt.ckpt_every_steps = None
-    rt.run(tail_steps, on_metrics=on_metrics)
+        def on_metrics(step, m):
+            jax.block_until_ready(rt.state)
+            marks.append((time.monotonic(), rt.checkpoints_taken,
+                          rt.ckpt.writing(), len(rec.compiles())))
+
+        c0, t0 = len(rec.compiles()), time.monotonic()
+        rt.run(1, on_metrics=on_metrics)
+        first_step_s = time.monotonic() - t0
+        first = rec.compiles()[c0:]
+        c1 = len(rec.compiles())
+        rt.run(every * saves - 1, on_metrics=on_metrics)
+        compiles_in_saves = len(rec.compiles()) - c1
+        host = host_copy(rt.state)
+        segment = len(marks)
+        rt.ckpt_every_steps = None
+        rt.run(tail_steps, on_metrics=on_metrics)
+    # a save's stall is its whole safe point: park, drain, snapshot
+    # (the D2H and any wait for the previous write) and commit
+    saving = {s.parent for s in rec.spans if s.name == "snapshot"}
+    stalls = [s.seconds for s in rec.spans
+              if s.name == "safe_point" and s.id in saving]
     steps: dict = {"clean": [], "writer": [], "compiling": []}
     for i, (a, b) in enumerate(zip(marks, marks[1:])):
         if a[1] != b[1] or i + 1 == segment:
@@ -182,8 +152,8 @@ def train_and_save(cfg, rc, ckpt_dir, *, mesh=None, every=2, saves=2,
         "host": host,
         "ref_losses": [h["loss"] for h in rt.history[-tail_steps:]],
         "first_step_s": first_step_s,
-        "compile_s": compile_s,
-        "compiles_first_step": compiles_first_step,
+        "compile_s": sum(c.seconds for c in first),
+        "compiles_first_step": len(first),
         "compiles_in_saves": compiles_in_saves,
         "step_times": steps,
         "stalls": stalls,
@@ -365,13 +335,16 @@ def main(argv=None) -> int:
         return 1
     from repro.launch.compile_cache import enable_compile_cache
     cache = enable_compile_cache(REPO)
-    counter = compile_counter()
     dev = devices[0]
     print(f"smoke: device {dev.platform} {dev.device_kind} x{len(devices)}, "
           f"compile cache {cache}", flush=True)
-    (four_chip if args.chips == 4 else one_chip)()
-    print(f"smoke: persistent cache hits {counter.cache_hits}, misses "
-          f"{counter.cache_misses}, compiles {counter.compiles}")
+    with tracing.recording() as rec:
+        (four_chip if args.chips == 4 else one_chip)()
+    events = [s.counts for s in rec.spans if s.name == "compile"]
+    print(f"smoke: persistent cache hits "
+          f"{sum(c['cache_hit'] for c in events)}, misses "
+          f"{sum(c['cache_miss'] for c in events)}, compiles "
+          f"{len(rec.compiles())}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(devices)}}))

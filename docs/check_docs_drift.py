@@ -23,6 +23,8 @@ Checks (each also run as a tier-1 test via tests/test_docs.py):
      `repro.core.image_store.MANIFEST_FIELDS`, plus the current
      MANIFEST_FORMAT is stated (ISSUE 10: the durable store's commit
      record cannot drift from the docs).
+  7. PERF.md's span table == the registry `repro.core.tracing.SPANS`
+     (span names, where each is opened, the counters taken inside it).
 
 Usage:  python docs/check_docs_drift.py   (exit 1 on any drift)
 """
@@ -181,6 +183,34 @@ def check_manifest_fields() -> list:
     return errors
 
 
+def check_span_table() -> list:
+    """PERF.md's span table vs repro.core.tracing.SPANS."""
+    from repro.core.tracing import SPANS
+    errors = []
+    text = _read("PERF.md")
+    anchor = "### Spans and counters"
+    if anchor not in text:
+        return [f"PERF.md is missing the {anchor!r} table"]
+    doc = {}
+    for cells in _md_table_rows(text, anchor):
+        m = re.fullmatch(r"`([a-z0-9_.]+)`", cells[0])
+        if m:
+            doc[m.group(1)] = (cells[1].strip("`"),
+                               tuple(re.findall(r"`([a-z0-9_]+)`",
+                                                cells[2])))
+    for name in sorted(set(SPANS) - set(doc)):
+        errors.append(f"PERF.md span table is missing span {name!r} "
+                      f"(present in tracing.SPANS)")
+    for name in sorted(set(doc) - set(SPANS)):
+        errors.append(f"PERF.md documents unknown span {name!r} "
+                      f"(absent from tracing.SPANS)")
+    for name in sorted(set(doc) & set(SPANS)):
+        if doc[name] != SPANS[name]:
+            errors.append(f"PERF.md span {name!r} reads {doc[name]}, "
+                          f"tracing.SPANS has {SPANS[name]}")
+    return errors
+
+
 def check_example_flags() -> list:
     """README 'Example flags' table + example epilog vs the parser."""
     import multirank_simulation as sim
@@ -236,7 +266,7 @@ def check_architecture_linked() -> list:
 
 CHECKS = (check_protocol_op_table, check_frame_format_table,
           check_image_container_fields, check_manifest_fields,
-          check_example_flags, check_quickstart_in_readme,
+          check_span_table, check_example_flags, check_quickstart_in_readme,
           check_architecture_linked)
 
 

@@ -39,6 +39,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core import tracing
 from repro.core.codec import (DEFAULT_COMPRESS_LEVEL, ChainPolicy,
                               CheckpointError, DeltaChainError, DeltaCodec,
                               ImageCodec, ImageError, ImageIntegrityError,
@@ -81,8 +82,9 @@ class _EncodeCtx:
     def base_array(self, path: str) -> Optional[np.ndarray]:
         if self.base_step is None:
             return None
-        return self._mgr._read_array(self._mgr.step_dir(self.base_step),
-                                     path)
+        with tracing.span("ckpt.base_read"):
+            return self._mgr._read_array(
+                self._mgr.step_dir(self.base_step), path)
 
 
 class _DecodeCtx:
@@ -186,17 +188,19 @@ class CheckpointManager:
         Returns a Future resolving to write stats.  A second save while
         one is in flight waits for it first (double buffering).
         """
-        self.wait()
-        t0 = time.monotonic()
-        host_tree = _to_host(state_tree)
-        snap_s = time.monotonic() - t0
-        logical_flat = (
-            {k: list(v) if isinstance(v, tuple) else None
-             for k, v in _flatten(logical_tree).items()}
-            if logical_tree is not None else {})
-        fut = self._writer.submit(self._write, step, host_tree, logical_flat,
-                                  extra or {}, snap_s)
-        self._pending = fut
+        with tracing.span("ckpt.save"):
+            self.wait()
+            with tracing.span("ckpt.d2h") as d2h:
+                host_tree = _to_host(state_tree)
+            logical_flat = (
+                {k: list(v) if isinstance(v, tuple) else None
+                 for k, v in _flatten(logical_tree).items()}
+                if logical_tree is not None else {})
+            fut = self._writer.submit(
+                self._write, step, host_tree, logical_flat, extra or {},
+                {"snapshot_s": round(d2h.seconds, 4),
+                 "d2h_bytes": d2h.counts.get("d2h_bytes", 0)})
+            self._pending = fut
         return fut
 
     def save(self, step: int, state_tree, logical_tree=None,
@@ -205,7 +209,8 @@ class CheckpointManager:
 
     def wait(self) -> None:
         if self._pending is not None:
-            self._pending.result()
+            with tracing.span("ckpt.wait"):
+                self._pending.result()
             self._pending = None
 
     def writing(self) -> bool:
@@ -226,13 +231,36 @@ class CheckpointManager:
 
     # ---- write path -----------------------------------------------------------
     def _write(self, step: int, host_tree, logical_flat, extra,
-               snap_s: float) -> Dict:
-        t0 = time.monotonic()
+               snap: Dict) -> Dict:
+        with tracing.span("ckpt.write") as w:
+            total = self._write_image(step, host_tree, logical_flat, extra)
+        # per-phase seconds of this write: base_read_s holds the base
+        # image's read and verify, encode_s the encode less that read;
+        # what is left of write_s is the write's own bookkeeping.  The
+        # write's bytes read back (the delta base) and sent to the
+        # device (digests, XOR and quantize kernels) beside them.
+        stats = {"step": step, "bytes": total, **snap,
+                 "write_s": round(w.seconds, 4),
+                 "base_read_s": round(w.total("ckpt.base_read"), 6),
+                 "encode_s": round(w.self_total("ckpt.encode"), 6),
+                 "digest_s": round(w.total("ckpt.digest"), 6),
+                 "file_s": round(w.total("ckpt.file_write"), 6),
+                 "commit_s": round(w.total("ckpt.commit"), 6),
+                 "bytes_read": w.counts.get("bytes_read", 0),
+                 "h2d_bytes": w.counts.get("h2d_bytes", 0)}
+        self.stats.append(stats)
+        return stats
+
+    def _write_image(self, step: int, host_tree, logical_flat,
+                     extra) -> int:
+        """Encode, digest and write one image, then commit it; returns
+        its payload bytes."""
         d = self.step_dir(step)
         tmp = d + ".tmp"
-        if os.path.exists(tmp):
-            shutil.rmtree(tmp)
-        os.makedirs(tmp)
+        with tracing.span("ckpt.file_write"):
+            if os.path.exists(tmp):
+                shutil.rmtree(tmp)
+            os.makedirs(tmp)
         flat = _flatten(host_tree)
         arrays: Dict[str, Dict] = {}
         total = 0
@@ -241,12 +269,16 @@ class CheckpointManager:
                     and self._since_full < self.full_every - 1)
         ctx = _EncodeCtx(self, prev_step if delta_ok else None)
         for path, arr in flat.items():
-            arr = np.asarray(arr)
-            for codec in self.codecs:
-                encoded = codec.encode(path, arr, ctx)
-                if encoded is not None:
-                    break
-            encoding, payloads, meta = encoded
+            with tracing.span("ckpt.encode"):
+                arr = np.asarray(arr)
+                for codec in self.codecs:
+                    encoded = codec.encode(path, arr, ctx)
+                    if encoded is not None:
+                        break
+                encoding, payloads, meta = encoded
+                if self.compress:
+                    payloads = [zlib.compress(p, self.compress_level)
+                                for p in payloads]
             entry: Dict[str, Any] = {
                 "shape": list(arr.shape),
                 "dtype": str(arr.dtype),
@@ -256,23 +288,31 @@ class CheckpointManager:
             }
             if self.compress:
                 entry["compressed"] = True
-                payloads = [zlib.compress(p, self.compress_level)
-                            for p in payloads]
             files = []
             for pi, payload in enumerate(payloads):
-                chunks = [payload[o:o + CHUNK_BYTES]
-                          for o in range(0, max(len(payload), 1), CHUNK_BYTES)]
-                for ci, chunk in enumerate(chunks):
+                for ci, o in enumerate(range(0, max(len(payload), 1),
+                                             CHUNK_BYTES)):
                     fname = f"{path.replace('/', '.')}-{pi}.{ci}"
-                    with open(os.path.join(tmp, fname), "wb") as f:
-                        f.write(chunk)
+                    with tracing.span("ckpt.file_write"):
+                        chunk = payload[o:o + CHUNK_BYTES]
+                        with open(os.path.join(tmp, fname), "wb") as f:
+                            f.write(chunk)
+                        tracing.count("bytes_written", len(chunk))
+                    with tracing.span("ckpt.digest"):
+                        digest = shard_digest(chunk, self.use_pallas)
                     files.append({"file": fname, "part": pi,
                                   "nbytes": len(chunk),
-                                  "checksum": shard_digest(
-                                      chunk, self.use_pallas)})
+                                  "checksum": digest})
                     total += len(chunk)
             entry["files"] = files
             arrays[path] = entry
+        with tracing.span("ckpt.commit"):
+            self._commit(step, d, tmp, arrays, extra, total)
+        return total
+
+    def _commit(self, step: int, d: str, tmp: str, arrays: Dict, extra,
+                total: int) -> None:
+        """Manifest last, atomic rename, then GC of older images."""
         manifest = {
             "format_version": 2,
             "step": step,
@@ -300,12 +340,7 @@ class CheckpointManager:
             os.replace(tmp, d)  # atomic commit
         wrote_delta = any("base_step" in e for e in arrays.values())
         self._since_full = self._since_full + 1 if wrote_delta else 0
-        stats = {"step": step, "bytes": total,
-                 "snapshot_s": round(snap_s, 4),
-                 "write_s": round(time.monotonic() - t0, 4)}
-        self.stats.append(stats)
         self._gc()
-        return stats
 
     def _gc(self) -> None:
         steps = self.steps()
@@ -338,17 +373,23 @@ class CheckpointManager:
         for fmeta in entry["files"]:
             if fmeta["part"] != part:
                 continue
-            with open(os.path.join(d, fmeta["file"]), "rb") as f:
-                chunk = f.read()
+            with tracing.span("ckpt.file_read"):
+                with open(os.path.join(d, fmeta["file"]), "rb") as f:
+                    chunk = f.read()
+                tracing.count("bytes_read", len(chunk))
             if self.verify:
-                got = shard_digest(chunk, self.use_pallas)
+                with tracing.span("ckpt.verify"):
+                    got = shard_digest(chunk, self.use_pallas)
                 if got != fmeta["checksum"]:
                     raise ImageIntegrityError(
                         f"checksum mismatch in {fmeta['file']}: "
                         f"{got} != {fmeta['checksum']}")
-            buf += chunk
+            # the join is a phase of the decode, timed on its own
+            with tracing.span("ckpt.decode"), tracing.span("ckpt.join"):
+                buf += chunk
         if entry.get("compressed"):
-            buf = zlib.decompress(buf)
+            with tracing.span("ckpt.decode"):
+                buf = zlib.decompress(buf)
         return buf
 
     def _read_array(self, d: str, path: str, *,
@@ -358,7 +399,8 @@ class CheckpointManager:
                 f"{path}: delta chain longer than the max_chain bound "
                 f"({self.max_chain})")
         try:
-            man = self._manifest(d)
+            with tracing.span("ckpt.file_read"):
+                man = self._manifest(d)
         except FileNotFoundError:
             return None
         entry = man["arrays"].get(path)
@@ -369,7 +411,8 @@ class CheckpointManager:
             raise CheckpointError(f"unknown encoding {entry['encoding']}")
         n_parts = 1 + max((f["part"] for f in entry["files"]), default=0)
         parts = [self._read_payload(d, entry, pi) for pi in range(n_parts)]
-        return codec.decode(parts, entry, _DecodeCtx(self, path, _depth))
+        with tracing.span("ckpt.decode"):
+            return codec.decode(parts, entry, _DecodeCtx(self, path, _depth))
 
     def restore(self, step: Optional[int] = None, *, mesh=None, specs=None,
                 skeleton=None) -> Tuple[Any, Dict]:
@@ -379,25 +422,27 @@ class CheckpointManager:
 
         Returns (state_tree, extra).
         """
-        step = self.latest_step() if step is None else step
-        if step is None:
-            raise CheckpointError("no checkpoints found")
-        d = self.step_dir(step)
-        man = self._manifest(d)
-        flat = {p: self._read_array(d, p) for p in man["arrays"]}
-        spec_flat = _flatten(specs) if specs is not None else {}
+        with tracing.span("ckpt.restore"):
+            step = self.latest_step() if step is None else step
+            if step is None:
+                raise CheckpointError("no checkpoints found")
+            d = self.step_dir(step)
+            with tracing.span("ckpt.file_read"):
+                man = self._manifest(d)
+            flat = {p: self._read_array(d, p) for p in man["arrays"]}
+            spec_flat = _flatten(specs) if specs is not None else {}
 
-        def bind(path, arr):
-            if mesh is None:
-                return arr
-            import jax
-            from jax.sharding import NamedSharding, PartitionSpec
-            spec = spec_flat.get(path, PartitionSpec())
-            return jax.device_put(arr, NamedSharding(mesh, spec))
+            def bind(path, arr):
+                if mesh is None:
+                    return arr
+                import jax
+                from jax.sharding import NamedSharding, PartitionSpec
+                spec = spec_flat.get(path, PartitionSpec())
+                tracing.count("h2d_bytes", arr.nbytes)
+                return jax.device_put(arr, NamedSharding(mesh, spec))
 
-        bound = {p: bind(p, a) for p, a in flat.items()}
-        tree = _rebuild(bound)
-        return tree, man["extra"]
+            bound = {p: bind(p, a) for p, a in flat.items()}
+        return _rebuild(bound), man["extra"]
 
 
 def _to_host(tree):
@@ -405,7 +450,9 @@ def _to_host(tree):
 
     def get(x):
         if hasattr(x, "addressable_shards") or hasattr(x, "device_buffer"):
-            return np.asarray(jax.device_get(x))
+            host = np.asarray(jax.device_get(x))
+            tracing.count("d2h_bytes", host.nbytes)
+            return host
         return np.asarray(x)
 
     return jax.tree.map(get, tree)

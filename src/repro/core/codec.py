@@ -45,8 +45,9 @@ from typing import Any, Dict, List, Optional, Tuple, Union
 
 import numpy as np
 
-from repro.kernels.checksum.ref import checksum_np
-from repro.kernels.delta.ref import apply_np, delta_np
+from repro.core import tracing
+from repro.kernels.checksum.ref import BLOCK, checksum_np
+from repro.kernels.delta.ref import DBLOCK, apply_np, delta_np
 from repro.kernels.quantize import ref as quant_ref
 
 # The pallas ops modules import jax; this module must stay importable
@@ -56,10 +57,16 @@ from repro.kernels.quantize import ref as quant_ref
 # for.
 
 
+def _word_bytes(nbytes: int, block: int) -> int:
+    """Bytes of the zero-padded word stream `host_words` uploads."""
+    return -(-nbytes // (4 * block)) * 4 * block
+
+
 def _delta_dispatch(cur: np.ndarray, prev: np.ndarray,
                     use_pallas: bool) -> np.ndarray:
     if use_pallas:
         from repro.kernels.delta.ops import delta_host
+        tracing.count("h2d_bytes", 2 * _word_bytes(cur.nbytes, DBLOCK))
         return delta_host(cur, prev, use_pallas=True)
     return delta_np(cur, prev)
 
@@ -67,6 +74,7 @@ def _delta_dispatch(cur: np.ndarray, prev: np.ndarray,
 def _quantize_dispatch(x: np.ndarray, use_pallas: bool):
     if use_pallas:
         from repro.kernels.quantize.ops import quantize_host
+        tracing.count("h2d_bytes", np.asarray(x).nbytes)
         return quantize_host(x, use_pallas=True)
     return quant_ref.quantize_np(x)
 
@@ -226,6 +234,7 @@ def shard_digest(data: bytes, use_pallas: bool = False) -> int:
     """Fletcher digest of one payload chunk (write AND restore path)."""
     if use_pallas:
         from repro.kernels.checksum.ops import checksum_host
+        tracing.count("h2d_bytes", _word_bytes(len(data), BLOCK))
         return checksum_host(np.frombuffer(data, np.uint8), use_pallas=True)
     return checksum_np(np.frombuffer(data, np.uint8))
 

@@ -27,6 +27,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import ModelConfig, RunConfig, ShapeConfig
+from repro.core import tracing
 from repro.core.checkpoint import CheckpointManager
 from repro.core.control import make_control_plane
 from repro.core.split_state import LowerHalf
@@ -80,50 +81,51 @@ class MANARuntime:
                  install_signal_handler: bool = False,
                  transport: str = "inproc", fault_plan=None,
                  async_ckpt: bool = False, use_pallas: bool = False):
-        self.cfg, self.rc = cfg, rc
-        self.seed = seed
-        # lower half: rebuilt at restart — including the comm world, so
-        # a checkpoint taken over one transport restores over another.
-        # fault_plan installs deterministic chaos on that world (used
-        # by the chaos suite to prove the runtime's checkpoint cycle is
-        # delay-tolerant).
-        self.lower = LowerHalf.build(cfg, rc, mesh, transport=transport,
-                                     fault_plan=fault_plan)
-        _, self.logical = abstract_params(cfg)
-        self.dataset = SyntheticDataset(cfg, rc.shape, seed=seed)
-        self.ckpt = CheckpointManager(
-            ckpt_dir, keep=keep,
-            quantize_keys=("opt/m", "opt/v") if quantize_moments else (),
-            delta_keys=("params",) if delta_params else (),
-            use_pallas=use_pallas)
-        # protocol plane (1 real rank; protocol is rank-agnostic).  The
-        # coordinator is an ENDPOINT on the fabric, not a shared object:
-        # the runtime talks to it through the same wire protocol a
-        # thousand-rank socket job would use (repro.core.control).
-        self.fabric = self.lower.comm
-        self.coord_server, clients = make_control_plane(self.fabric)
-        self.coord = clients[0]
-        self.agent = RankAgent(0, self.fabric.endpoints[0], self.coord,
-                               [0], mode=mode, transport=transport,
-                               async_commit=async_ckpt)
-        # server thread + sockets die with the runtime even if close()
-        # is never called (tests churn through many runtimes)
-        self._finalizer = weakref.finalize(
-            self, MANARuntime._teardown, self.coord_server, self.fabric)
-        self.ckpt_every_steps = ckpt_every_steps
-        self.ckpt_every_secs = ckpt_every_secs
-        self._last_ckpt_time = time.monotonic()
-        self.state: Any = None
-        self.history: List[Dict] = []
-        self.checkpoints_taken = 0
-        # the handler only sets a flag: requesting a checkpoint is now a
-        # WIRE call (send + blocking reply on this rank's endpoint), and
-        # a signal landing while the main thread holds that endpoint's
-        # lock would self-deadlock if the handler called it directly
-        self._preempted = False
-        if install_signal_handler:
-            signal.signal(signal.SIGUSR1,
-                          lambda *_: setattr(self, "_preempted", True))
+        with tracing.span("runtime.build"):
+            self.cfg, self.rc = cfg, rc
+            self.seed = seed
+            # lower half: rebuilt at restart — including the comm world, so
+            # a checkpoint taken over one transport restores over another.
+            # fault_plan installs deterministic chaos on that world (used
+            # by the chaos suite to prove the runtime's checkpoint cycle is
+            # delay-tolerant).
+            self.lower = LowerHalf.build(cfg, rc, mesh, transport=transport,
+                                         fault_plan=fault_plan)
+            _, self.logical = abstract_params(cfg)
+            self.dataset = SyntheticDataset(cfg, rc.shape, seed=seed)
+            self.ckpt = CheckpointManager(
+                ckpt_dir, keep=keep,
+                quantize_keys=("opt/m", "opt/v") if quantize_moments else (),
+                delta_keys=("params",) if delta_params else (),
+                use_pallas=use_pallas)
+            # protocol plane (1 real rank; protocol is rank-agnostic).  The
+            # coordinator is an ENDPOINT on the fabric, not a shared object:
+            # the runtime talks to it through the same wire protocol a
+            # thousand-rank socket job would use (repro.core.control).
+            self.fabric = self.lower.comm
+            self.coord_server, clients = make_control_plane(self.fabric)
+            self.coord = clients[0]
+            self.agent = RankAgent(0, self.fabric.endpoints[0], self.coord,
+                                   [0], mode=mode, transport=transport,
+                                   async_commit=async_ckpt)
+            # server thread + sockets die with the runtime even if close()
+            # is never called (tests churn through many runtimes)
+            self._finalizer = weakref.finalize(
+                self, MANARuntime._teardown, self.coord_server, self.fabric)
+            self.ckpt_every_steps = ckpt_every_steps
+            self.ckpt_every_secs = ckpt_every_secs
+            self._last_ckpt_time = time.monotonic()
+            self.state: Any = None
+            self.history: List[Dict] = []
+            self.checkpoints_taken = 0
+            # the handler only sets a flag: requesting a checkpoint is now a
+            # WIRE call (send + blocking reply on this rank's endpoint), and
+            # a signal landing while the main thread holds that endpoint's
+            # lock would self-deadlock if the handler called it directly
+            self._preempted = False
+            if install_signal_handler:
+                signal.signal(signal.SIGUSR1,
+                              lambda *_: setattr(self, "_preempted", True))
 
     # ---- lifecycle -----------------------------------------------------------
     def initialize(self) -> None:
@@ -146,15 +148,19 @@ class MANARuntime:
         """Elastic restart: rebind the upper half onto THIS lower half
         (which may have a different mesh shape — or a different
         transport — than the writer's)."""
-        state, extra = self.ckpt.restore(
-            step, mesh=self.lower.mesh,
-            specs=self.lower.state_specs if self.lower.mesh is not None
-            else None)
-        # jax-ify on single device
-        if self.lower.mesh is None:
-            state = jax.tree.map(jax.numpy.asarray, state)
-        # scalars come back as 0-d arrays
-        self.state = state
+        with tracing.span("restore"):
+            state, extra = self.ckpt.restore(
+                step, mesh=self.lower.mesh,
+                specs=self.lower.state_specs if self.lower.mesh is not None
+                else None)
+            # bound when the leaves are on the device (the first step
+            # would wait for them anyway)
+            with tracing.span("restore.bind"):
+                if self.lower.mesh is None:
+                    tracing.count("h2d_bytes", sum(
+                        np.asarray(x).nbytes for x in jax.tree.leaves(state)))
+                    state = jax.tree.map(jax.numpy.asarray, state)
+                self.state = jax.block_until_ready(state)
         meta = extra.get("run_meta", {})
         if meta.get("arch") and meta["arch"] != self.cfg.arch_id:
             raise ValueError(
@@ -213,20 +219,21 @@ class MANARuntime:
             step = int(np.asarray(jax.device_get(self.state["step"])))
             if stop_flag is not None and stop_flag():
                 break
-            batch = self.dataset.get_batch(step)
-            batch = {k: jax.numpy.asarray(v) for k, v in batch.items()}
-            if self.lower.mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec as P
-                from repro.sharding.rules import batch_axes
-                b = batch_axes(self.lower.mesh)
-                batch = {k: jax.device_put(v, NamedSharding(
-                    self.lower.mesh, P(b, *([None] * (v.ndim - 1)))))
-                    for k, v in batch.items()}
-            self.state, metrics = self.lower.train_step(self.state, batch)
-            metrics = {k: float(np.asarray(jax.device_get(v)))
-                       for k, v in metrics.items()}
-            metrics["step"] = step
-            self.history.append(metrics)
+            with tracing.span("step"):
+                batch = self.dataset.get_batch(step)
+                batch = {k: jax.numpy.asarray(v) for k, v in batch.items()}
+                if self.lower.mesh is not None:
+                    from jax.sharding import NamedSharding, PartitionSpec as P
+                    from repro.sharding.rules import batch_axes
+                    b = batch_axes(self.lower.mesh)
+                    batch = {k: jax.device_put(v, NamedSharding(
+                        self.lower.mesh, P(b, *([None] * (v.ndim - 1)))))
+                        for k, v in batch.items()}
+                self.state, metrics = self.lower.train_step(self.state, batch)
+                metrics = {k: float(np.asarray(jax.device_get(v)))
+                           for k, v in metrics.items()}
+                metrics["step"] = step
+                self.history.append(metrics)
             if on_metrics is not None:
                 on_metrics(step, metrics)
             # MANA safe point: step boundary (outside any dispatch)

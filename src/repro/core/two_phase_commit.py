@@ -33,6 +33,7 @@ from typing import Any, Callable, Dict, Optional, Sequence
 
 from repro.comm import collectives as coll
 from repro.comm.fabric import Endpoint
+from repro.core import tracing
 from repro.core.coordinator import CheckpointAborted, Coordinator
 from repro.core.drain import drain_rank
 from repro.core.virtual import VirtualCommTable, VirtualRequestTable, comm_gid
@@ -68,14 +69,6 @@ class RankAgent:
         self._writer = writer
         self.done_epoch = 0
         self.ckpt_epoch = 0  # adopted epoch of the snapshot in progress
-        # post-closure compute stall of the LAST checkpoint taken at
-        # this rank: seconds from the "safe" park verdict (the drain
-        # barrier) back to compute — drain + snapshot/stage + (sync:
-        # ship + commit round trips | async: writer submit).  This is
-        # the §III quantity the async split shrinks, and what the
-        # ckpt_stall benchmark records; park/alignment time is excluded
-        # (workload skew, not protocol cost).
-        self.last_commit_stall_s = 0.0
         # upper-half tables (serialized into every checkpoint)
         self.comms = VirtualCommTable()
         self.requests = VirtualRequestTable()
@@ -230,16 +223,25 @@ class RankAgent:
         """
         if not self._ckpt_pending():
             return False
+        # spans: park (phase 1), then drain, snapshot and commit, the
+        # post-closure stall the async split shrinks
+        with tracing.span("safe_point"):
+            return self._checkpoint(snapshot, timeout)
+
+    def _checkpoint(self, snapshot: Callable[[], None],
+                    timeout: float) -> bool:
         epoch = self.coord.intent_epoch
         assert self.in_lower_half == 0, "safe point inside lower half"
-        if self.mode == "nobarrier":
-            # flawed revision: park unconditionally, no count handshake
-            verdict = self.coord.try_park(self.rank, epoch, {},
-                                          timeout=timeout)
-        else:
-            verdict = self.coord.try_park(self.rank, epoch,
-                                          dict(self.coll_counts),
-                                          timeout=timeout)
+        with tracing.span("park"):
+            if self.mode == "nobarrier":
+                # flawed revision: park unconditionally, no count
+                # handshake
+                verdict = self.coord.try_park(self.rank, epoch, {},
+                                              timeout=timeout)
+            else:
+                verdict = self.coord.try_park(self.rank, epoch,
+                                              dict(self.coll_counts),
+                                              timeout=timeout)
         if verdict == "continue":
             self.stats["continues"] += 1
             return False
@@ -251,11 +253,11 @@ class RankAgent:
         # mid-phase-1, ranks parked under different epoch numbers all
         # completed the SAME physical cut, and phase 2 must agree on one
         # epoch or commit/release bookkeeping misaligns
-        stall_t0 = time.monotonic()
         epoch = max(epoch, self.coord.last_closed_epoch)
         world = self.comm_ranks(self.world_comm)
-        drain_rank(self.ep, world, gid=comm_gid(world), timeout=timeout,
-                   algo=self.coll_algo)
+        with tracing.span("drain"):
+            drain_rank(self.ep, world, gid=comm_gid(world), timeout=timeout,
+                       algo=self.coll_algo)
         ok = False
         # the adopted epoch this snapshot belongs to — snapshot
         # callbacks that ship their blob to the launcher-side image
@@ -265,26 +267,29 @@ class RankAgent:
             # the 2PC split: stage at the cut, hand the expensive tail
             # to the background writer, resume compute NOW.  `committed`
             # here means "staged"; the epoch finalizes at writer-ack.
-            staged = snapshot()
-            self.coord.report_committed(self.rank, epoch)
-            self.stats["async_stages"] += 1
-            produce = staged if callable(staged) else (lambda: None)
-            self._ensure_writer().submit(
-                epoch, produce,
-                lambda e, okk, payload: self._writer_done(e, okk, payload))
+            with tracing.span("snapshot"):
+                staged = snapshot()
+            with tracing.span("commit"):
+                self.coord.report_committed(self.rank, epoch)
+                self.stats["async_stages"] += 1
+                produce = staged if callable(staged) else (lambda: None)
+                self._ensure_writer().submit(
+                    epoch, produce,
+                    lambda e, okk, payload: self._writer_done(e, okk,
+                                                              payload))
             self.done_epoch = epoch
-            self.last_commit_stall_s = time.monotonic() - stall_t0
             return True
         try:
-            snapshot()
-            self.coord.report_committed(self.rank)
-            if self.rank == min(world):
-                self.coord.wait_all_committed(epoch, timeout=timeout)
-            ok = self.coord.wait_released(epoch, timeout=timeout)
+            with tracing.span("snapshot"):
+                snapshot()
+            with tracing.span("commit"):
+                self.coord.report_committed(self.rank)
+                if self.rank == min(world):
+                    self.coord.wait_all_committed(epoch, timeout=timeout)
+                ok = self.coord.wait_released(epoch, timeout=timeout)
         except CheckpointAborted:
             ok = False
         self.done_epoch = epoch
-        self.last_commit_stall_s = time.monotonic() - stall_t0
         return ok
 
     # ---- serialization (upper half) -----------------------------------------------
