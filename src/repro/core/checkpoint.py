@@ -35,7 +35,7 @@ import shutil
 import time
 import zlib
 from concurrent.futures import Future, ThreadPoolExecutor
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -368,28 +368,41 @@ class CheckpointManager:
         with open(os.path.join(d, MANIFEST)) as f:
             return json.load(f)
 
-    def _read_payload(self, d: str, entry: Dict, part: int) -> bytes:
-        buf = b""
-        for fmeta in entry["files"]:
-            if fmeta["part"] != part:
-                continue
+    def _read_payload(self, d: str, entry: Dict,
+                      part: int) -> Union[np.ndarray, bytes]:
+        """One payload part, its chunk files read in manifest order into
+        one buffer allocated at the part's size.  Each file must hold
+        the bytes its manifest entry says; with `verify` each chunk's
+        digest is checked before anything decodes it.  Returns the
+        writable buffer, or the decompressed `bytes` of a compressed
+        part."""
+        files = [f for f in entry["files"] if f["part"] == part]
+        buf = np.empty(sum(f["nbytes"] for f in files), np.uint8)
+        view = memoryview(buf)
+        off = 0
+        for fmeta in files:
+            n = fmeta["nbytes"]
+            chunk = view[off:off + n]
+            off += n
             with tracing.span("ckpt.file_read"):
                 with open(os.path.join(d, fmeta["file"]), "rb") as f:
-                    chunk = f.read()
-                tracing.count("bytes_read", len(chunk))
+                    size = os.fstat(f.fileno()).st_size
+                    got = f.readinto(chunk) if size == n else 0
+                tracing.count("bytes_read", got)
+            if size != n or got != n:
+                raise ImageIntegrityError(
+                    f"size mismatch in {fmeta['file']}: {size} bytes on "
+                    f"disk, {got} read, {n} in the manifest")
             if self.verify:
                 with tracing.span("ckpt.verify"):
-                    got = shard_digest(chunk, self.use_pallas)
-                if got != fmeta["checksum"]:
+                    digest = shard_digest(chunk, self.use_pallas)
+                if digest != fmeta["checksum"]:
                     raise ImageIntegrityError(
                         f"checksum mismatch in {fmeta['file']}: "
-                        f"{got} != {fmeta['checksum']}")
-            # the join is a phase of the decode, timed on its own
-            with tracing.span("ckpt.decode"), tracing.span("ckpt.join"):
-                buf += chunk
+                        f"{digest} != {fmeta['checksum']}")
         if entry.get("compressed"):
             with tracing.span("ckpt.decode"):
-                buf = zlib.decompress(buf)
+                return zlib.decompress(buf)
         return buf
 
     def _read_array(self, d: str, path: str, *,

@@ -142,9 +142,11 @@ class ImageCodec:
 
     `encode` returns (encoding_name, payload_parts, manifest_meta) when
     this codec claims the array, or None to pass to the next codec in
-    the stack.  `decode` inverts it.  `ctx` is the manager-provided
-    context: `ctx.base_array(path)` reads the array from the delta-base
-    image, `ctx.use_pallas` selects the kernel or oracle path.
+    the stack.  `decode` inverts it from each part's buffer: a writable
+    `np.uint8` array as read from the image, or `bytes` (a decompressed
+    part).  `ctx` is the manager-provided context: `ctx.base_array(path)`
+    reads the array from the delta-base image, `ctx.use_pallas` selects
+    the kernel or oracle path.
     """
 
     name = "abstract"
@@ -162,7 +164,8 @@ class ImageCodec:
             Tuple[str, List[bytes], Dict]]:
         raise NotImplementedError
 
-    def decode(self, parts: List[bytes], entry: Dict, ctx) -> np.ndarray:
+    def decode(self, parts: List[Union[np.ndarray, bytes]], entry: Dict,
+               ctx) -> np.ndarray:
         raise NotImplementedError
 
 
@@ -177,7 +180,13 @@ class RawCodec(ImageCodec):
     def decode(self, parts, entry, ctx):
         shape = tuple(entry["shape"])
         dtype = np.dtype(entry["dtype"])
-        return np.frombuffer(parts[0], dtype).reshape(shape).copy()
+        out = np.frombuffer(parts[0], dtype).reshape(shape)
+        if not out.flags.writeable:
+            # a read-only buffer (bytes) is copied, so that every
+            # restored leaf is writable and owns its memory
+            out = out.copy()
+            tracing.count("decode_copy_bytes", out.nbytes)
+        return out
 
 
 class QuantizeCodec(ImageCodec):
@@ -230,7 +239,8 @@ class DeltaCodec(ImageCodec):
                         shape, dtype)
 
 
-def shard_digest(data: bytes, use_pallas: bool = False) -> int:
+def shard_digest(data: Union[bytes, memoryview],
+                 use_pallas: bool = False) -> int:
     """Fletcher digest of one payload chunk (write AND restore path)."""
     if use_pallas:
         from repro.kernels.checksum.ops import checksum_host

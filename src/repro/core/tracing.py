@@ -69,8 +69,7 @@ SPANS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "ckpt.restore": ("CheckpointManager.restore", ("h2d_bytes",)),
     "ckpt.file_read": ("CheckpointManager._read_payload", ("bytes_read",)),
     "ckpt.verify": ("CheckpointManager._read_payload", ("h2d_bytes",)),
-    "ckpt.decode": ("CheckpointManager._read_array", ()),
-    "ckpt.join": ("CheckpointManager._read_payload", ()),
+    "ckpt.decode": ("CheckpointManager._read_array", ("decode_copy_bytes",)),
     "restore": ("MANARuntime.restore", ()),
     "restore.bind": ("MANARuntime.restore", ("h2d_bytes",)),
     "compile": ("jax.monitoring listener", ("cache_hit", "cache_miss")),

@@ -1,7 +1,12 @@
-"""CheckpointManager: roundtrip, integrity, encodings, GC, async — and
-property-based fuzzing of the `_flatten`/`_rebuild` tree codec."""
+"""CheckpointManager: roundtrip, integrity, encodings, GC, async, leaves
+read from several chunk files into one buffer, images of the earlier
+reader — and property-based fuzzing of the `_flatten`/`_rebuild` tree
+codec."""
+import json
 import os
 import random
+import re
+import tarfile
 
 import numpy as np
 import pytest
@@ -10,8 +15,11 @@ try:
 except ImportError:  # minimal env: deterministic fallback sampler
     from _hypothesis_fallback import given, settings, st
 
-from repro.core.checkpoint import (CheckpointError, CheckpointManager,
+from repro.core import checkpoint
+from repro.core.checkpoint import (MANIFEST, CheckpointError,
+                                   CheckpointManager, ImageIntegrityError,
                                    _flatten, _rebuild)
+from repro.kernels.quantize.ref import dequantize_np, quantize_np
 
 
 def _tree(seed=0):
@@ -122,6 +130,131 @@ def test_rewrite_same_step_and_crash_recovery(tmp_path):
     assert CheckpointManager(str(tmp_path)).steps() == [5]  # recovered
     out, _ = CheckpointManager(str(tmp_path)).restore(5)
     np.testing.assert_array_equal(out["x"], np.ones(4, np.float32))
+
+
+# ---------------------------------------------------------------------------
+# leaves of several chunk files: each part is read into one buffer
+# ---------------------------------------------------------------------------
+
+CHUNK = 1024  # bytes per chunk file here: the array leaves span several
+# the images `_save_stack` wrote, with chunks of CHUNK bytes, before the
+# read path read each part into one preallocated buffer
+EARLIER_IMAGES = os.path.join(os.path.dirname(__file__), "data",
+                              "chunked_images.tar.gz")
+# stack -> (manager options, steps saved in order)
+STACKS = {
+    "raw": ({}, (1,)),
+    "xor_delta_chain": ({"delta_keys": ("params",)}, (1, 2, 3)),
+    "int8_moments": ({"quantize_keys": ("opt/m",)}, (1,)),
+    "compressed": ({"compress": True}, (1,)),
+}
+
+
+@pytest.fixture
+def small_chunks(monkeypatch):
+    monkeypatch.setattr(checkpoint, "CHUNK_BYTES", CHUNK)
+
+
+def _chunky_tree(seed):
+    rng = np.random.RandomState(seed)
+
+    def f32(*shape):
+        return rng.randn(*shape).astype(np.float32)
+
+    return {"params": {"w": f32(32, 48), "b": f32(300)},
+            "opt": {"m": {"w": f32(32, 48), "b": f32(300)}},
+            "step": np.int32(seed)}
+
+
+def _save_stack(directory, stack):
+    opts, steps = STACKS[stack]
+    mgr = CheckpointManager(directory, **opts)
+    for step in steps:
+        mgr.save(step, _chunky_tree(step))
+    return mgr
+
+
+def _expected(stack, step):
+    """The leaves a restore of `step` gives, by path: the saved ones,
+    the moments through the int8 codec's numpy oracle."""
+    want = _flatten(_chunky_tree(step))
+    if stack == "int8_moments":
+        for path, x in want.items():
+            if path.startswith("opt/m"):
+                want[path] = dequantize_np(*quantize_np(x), x.shape, x.dtype)
+    return want
+
+
+def _assert_bit_exact(out, want):
+    got = _flatten(out)
+    assert got.keys() == want.keys()
+    for path, x in want.items():
+        x = np.asarray(x)
+        assert (got[path].dtype, got[path].shape) == (x.dtype, x.shape)
+        assert got[path].tobytes() == x.tobytes(), path
+
+
+@pytest.mark.parametrize("stack", list(STACKS))
+def test_leaves_of_several_chunks_round_trip_bit_exactly(tmp_path,
+                                                         small_chunks, stack):
+    mgr = _save_stack(str(tmp_path), stack)
+    step = STACKS[stack][1][-1]
+    man = mgr._manifest(mgr.step_dir(step))["arrays"]
+    for path in ("params/w", "opt/m/w"):
+        assert len([f for f in man[path]["files"] if f["part"] == 0]) > 1
+    if stack == "xor_delta_chain":
+        assert man["params/w"]["encoding"] == "xor_delta"
+    out, _ = mgr.restore(step)
+    _assert_bit_exact(out, _expected(stack, step))
+    # every leaf is writable and owns its memory
+    leaves = list(_flatten(out).values())
+    for i, a in enumerate(leaves):
+        assert a.flags.writeable
+        assert not any(np.shares_memory(a, b) for b in leaves[i + 1:])
+
+
+@pytest.mark.parametrize("verify", [True, False])
+@pytest.mark.parametrize("fault", ["cut_short", "bytes_appended"])
+def test_a_chunk_file_of_the_wrong_size_raises(tmp_path, small_chunks,
+                                               fault, verify):
+    mgr = _save_stack(str(tmp_path), "raw")
+    d = mgr.step_dir(1)
+    name = mgr._manifest(d)["arrays"]["params/w"]["files"][1]["file"]
+    with open(os.path.join(d, name), "r+b") as f:
+        if fault == "cut_short":
+            f.truncate(CHUNK - 1)
+        else:
+            f.seek(0, os.SEEK_END)
+            f.write(b"\0")
+    reader = CheckpointManager(str(tmp_path), verify=verify)
+    with pytest.raises(ImageIntegrityError, match=re.escape(name)):
+        reader.restore(1)
+
+
+def test_images_of_the_earlier_reader_restore_and_rewrite_byte_for_byte(
+        tmp_path, small_chunks):
+    """The image format is unchanged: each image in EARLIER_IMAGES
+    restores bit-exactly, and the same saves write it again to the byte
+    (its manifest differs only in `written_at`)."""
+    with tarfile.open(EARLIER_IMAGES) as tar:
+        tar.extractall(tmp_path / "earlier", filter="data")
+    for stack, (_, steps) in STACKS.items():
+        earlier = CheckpointManager(str(tmp_path / "earlier" / stack))
+        again = _save_stack(str(tmp_path / "again" / stack), stack)
+        assert earlier.steps() == again.steps() == list(steps)
+        for step in steps:
+            a, b = earlier.step_dir(step), again.step_dir(step)
+            assert sorted(os.listdir(a)) == sorted(os.listdir(b))
+            for name in os.listdir(a):
+                with open(os.path.join(a, name), "rb") as fa, \
+                        open(os.path.join(b, name), "rb") as fb:
+                    old, new = fa.read(), fb.read()
+                if name == MANIFEST:
+                    old, new = json.loads(old), json.loads(new)
+                    old.pop("written_at"), new.pop("written_at")
+                assert old == new, (stack, step, name)
+            out, _ = earlier.restore(step)
+            _assert_bit_exact(out, _expected(stack, step))
 
 
 # ---------------------------------------------------------------------------

@@ -160,22 +160,26 @@ def test_device_bytes_counted_at_the_kernels(tmp_path):
     assert mgr.stats[-1]["h2d_bytes"] == write.counts["h2d_bytes"]
 
 
-def test_the_join_is_timed_inside_the_decode(tmp_path):
-    """Each chunk's join is a `ckpt.join` child of a `ckpt.decode`, so
-    the decode's time still holds the join and the join reads alone."""
-    mgr = CheckpointManager(str(tmp_path))
+@pytest.mark.parametrize("compress", [False, True])
+def test_restore_reads_into_one_buffer_and_counts_its_copies(tmp_path,
+                                                             compress):
+    """A restore records no join, reads the image's payload bytes, and
+    copies after the read only a read-only buffer: the raw leaves of a
+    compressed image, counted as `decode_copy_bytes`."""
+    mgr = CheckpointManager(str(tmp_path), compress=compress)
     mgr.save(1, _state(1))
     with tracing.recording() as rec:
         mgr.restore(1)
-    by_id = {s.id: s for s in rec.spans}
-    joins = [s for s in rec.spans if s.name == "ckpt.join"]
-    assert len(joins) == len(_state(1)["params"]) + 2   # one chunk a leaf
-    for j in joins:
-        assert by_id[j.parent].name == "ckpt.decode"
-        assert by_id[by_id[j.parent].parent].name == "ckpt.restore"
+    assert "ckpt.join" not in {s.name for s in rec.spans}
+    (restore,) = [s for s in rec.spans if s.name == "ckpt.restore"]
+    assert restore.counts["bytes_read"] == _payload_bytes(mgr.step_dir(1))
+    state = _state(1)
+    leaves = [*state["params"].values(), state["opt"]["m"], state["step"]]
+    copied = sum(x.nbytes for x in leaves) if compress else 0
     summary = rec.summary()
-    assert summary["ckpt.join"]["total_s"] <= summary["ckpt.decode"][
-        "total_s"] - summary["ckpt.decode"]["self_s"] + 1e-9
+    assert summary["ckpt.decode"]["counts"].get("decode_copy_bytes",
+                                                0) == copied
+    assert restore.counts.get("decode_copy_bytes", 0) == copied
 
 
 def test_compile_is_a_child_event_and_a_cached_call_records_none():
