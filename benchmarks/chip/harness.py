@@ -3,7 +3,11 @@
 A cell names a configuration (`configs/<name>.json`) and a traffic mix
 (`traffic/<name>.json`); per-layer metrics are readers
 (`metrics/<name>.py`).  The harness finds each by the name that
-`BENCHMARK.json` gives, so a new cell, mix or metric is new files.
+`BENCHMARK.json` gives, so a new cell, mix or metric is new files.  What
+is particular to a model family comes from the family module that the
+configuration's `reference` key names (`references/<family>.py`), so a
+new family is new files too.  A configuration's `layout.mesh` (axis
+name -> size) is the mesh its cells run on, one chip without it.
 
 A run: set-up (weights from the seed, made on the device in one jitted
 call; every shape the window uses warmed; the traffic's first steps
@@ -24,6 +28,7 @@ import functools
 import gc
 import importlib.util
 import json
+import math
 import os
 import shutil
 import statistics
@@ -57,6 +62,12 @@ def load_module(path: str, name: str):
     return mod
 
 
+# what a family module (`references/<family>.py`) gives: its plain
+# reference, its settings for the program, and its operation count
+FAMILY = ("param_shapes", "init_params", "loss", "program_model",
+          "train_flops_per_token")
+
+
 @functools.cache
 def _reference(name: str):
     return load_module(os.path.join(HERE, "references", name + ".py"),
@@ -64,8 +75,15 @@ def _reference(name: str):
 
 
 def reference(cfg: Dict):
-    """The plain reference of a configuration's model family."""
-    return _reference(cfg["reference"])
+    """The family module of a configuration: its plain reference and all
+    that is family-specific.  One that lacks a function is refused."""
+    mod = _reference(cfg["reference"])
+    missing = [f for f in FAMILY if not callable(getattr(mod, f, None))]
+    if missing:
+        raise AttributeError(
+            f"references/{cfg['reference']}.py, the family module of "
+            f"{cfg['name']}, lacks {', '.join(missing)}")
+    return mod
 
 
 def train_reference():
@@ -108,20 +126,12 @@ def batch(cfg: Dict, traffic: Dict, seed: int, step: int) -> Dict:
 # ---------------------------------------------------------------------------
 
 def program_config(cfg: Dict, traffic: Dict):
-    """The program's ModelConfig and RunConfig for a configuration file."""
+    """The program's ModelConfig (from the family module) and RunConfig
+    for a configuration file."""
     from repro.configs.base import ModelConfig, RunConfig, ShapeConfig
     run, opt = cfg["run"], cfg["run"]["optimizer"]
-    model = ModelConfig(
-        arch_id=cfg["name"], family="dense",
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        head_dim=cfg["hidden_size"] // cfg["num_attention_heads"],
-        qkv_bias=cfg["layout"]["qkv_bias"], rope_theta=cfg["rope_theta"],
-        norm_eps=cfg["rms_norm_eps"],
-        tie_embeddings=cfg["tie_word_embeddings"],
-        pad_to=cfg["layout"]["head_pad_to"], source=cfg["source"])
+    model = ModelConfig(arch_id=cfg["name"], source=cfg["source"],
+                        **reference(cfg).program_model(cfg))
     shape = ShapeConfig(traffic["name"], traffic["seq_len"], traffic["batch"],
                         "train")
     rc = RunConfig(model=model, shape=shape, remat_policy=run["remat_policy"],
@@ -133,10 +143,47 @@ def program_config(cfg: Dict, traffic: Dict):
     return model, rc
 
 
-def new_runtime(model, rc, cfg: Dict, ckpt_dir: str, seed: int):
+def mesh_chips(cfg: Dict) -> int:
+    """Chips a configuration runs on: the size of its `layout.mesh`
+    (axis name -> size), 1 without one."""
+    return math.prod(cfg["layout"].get("mesh", {}).values())
+
+
+def make_mesh(cfg: Dict):
+    """The configuration's mesh over the first chips, None without one."""
+    axes = cfg["layout"].get("mesh")
+    if not axes:
+        return None
+    from repro.launch.mesh import make_mesh as program_mesh
+    return program_mesh(tuple(axes.values()), tuple(axes))
+
+
+def state_shardings(rt):
+    """The runtime's own state shardings (`NamedSharding` tree), None
+    without a mesh."""
+    if rt.lower.mesh is None:
+        return None
+    from jax.sharding import NamedSharding, PartitionSpec
+    return jax.tree.map(lambda sp: NamedSharding(rt.lower.mesh, sp),
+                        rt.lower.state_specs,
+                        is_leaf=lambda x: isinstance(x, PartitionSpec))
+
+
+def batch_sharding(mesh):
+    """The placement the program gives a batch on a mesh (rows over its
+    data axes), None without a mesh."""
+    if mesh is None:
+        return None
+    from jax.sharding import NamedSharding, PartitionSpec
+    from repro.sharding.rules import batch_axes
+    return NamedSharding(mesh, PartitionSpec(batch_axes(mesh)))
+
+
+def new_runtime(model, rc, cfg: Dict, ckpt_dir: str, seed: int, mesh=None):
     from repro.core.runtime import MANARuntime
     ck = cfg["run"]["checkpoint"]
-    rt = MANARuntime(model, rc, ckpt_dir=ckpt_dir, seed=seed, keep=ck["keep"],
+    rt = MANARuntime(model, rc, ckpt_dir=ckpt_dir, seed=seed, mesh=mesh,
+                     keep=ck["keep"],
                      use_pallas=ck["use_pallas"],
                      delta_params=ck["delta_params"],
                      quantize_moments=ck["quantize_moments"])
@@ -215,26 +262,45 @@ def leaf_norms(tree, scale: float = 1.0) -> Dict[str, float]:
             for k, v in train_reference().leaf_norms(tree).items()}
 
 
-def change_norms(params, ref, cfg: Dict, seed: int) -> Dict[str, float]:
-    """Norm of each leaf's change from the seed's initial weights."""
+def change_norms(params, ref, cfg: Dict, seed: int,
+                 shardings=None) -> Dict[str, float]:
+    """Norm of each leaf's change from the seed's initial weights (made
+    with the params' shardings where the state has them)."""
     t = train_reference()
-    return t.leaf_norms(t.subtract(params, make_params(ref, cfg, seed)))
+    return t.leaf_norms(t.subtract(params, make_params(
+        ref, cfg, seed, shardings and shardings["params"])))
 
 
-def make_params(ref, cfg: Dict, seed: int):
-    return _jit_init(json.dumps(cfg, sort_keys=True))(np.uint32(seed))
+def make_params(ref, cfg: Dict, seed: int, shardings=None):
+    return _jit_init(json.dumps(cfg, sort_keys=True),
+                     _key(shardings))(np.uint32(seed))
+
+
+def _key(shardings):
+    """A sharding tree as a cache key: (treedef, leaves), or None."""
+    if shardings is None:
+        return None
+    leaves, treedef = jax.tree.flatten(shardings)
+    return treedef, tuple(leaves)
+
+
+def _jit_placed(fn, key):
+    """jit of fn, its outputs placed by the sharding tree of a `_key`."""
+    return jax.jit(fn, out_shardings=key and jax.tree.unflatten(*key))
 
 
 @functools.cache
-def _jit_init(cfg_json: str):
+def _jit_init(cfg_json: str, shardings):
     cfg = json.loads(cfg_json)
     ref = reference(cfg)
-    return jax.jit(lambda s: ref.init_params(cfg, s))
+    return _jit_placed(lambda s: ref.init_params(cfg, s), shardings)
 
 
-def make_state(ref, cfg: Dict, seed: int, abstract) -> Dict:
-    """The program's state tree: the seed's weights, zero moments."""
-    fn = _jit_state(json.dumps(cfg, sort_keys=True))
+def make_state(ref, cfg: Dict, seed: int, abstract, shardings=None) -> Dict:
+    """The program's state tree: the seed's weights, zero moments, built
+    in one jitted call and placed by the state's shardings (no device
+    ever holds the whole of a sharded state)."""
+    fn = _jit_state(json.dumps(cfg, sort_keys=True), _key(shardings))
     want = jax.tree.map(lambda s: (tuple(s.shape), str(s.dtype)), abstract)
     got = jax.tree.map(lambda s: (tuple(s.shape), str(s.dtype)),
                        jax.eval_shape(fn, np.uint32(seed)))
@@ -245,7 +311,7 @@ def make_state(ref, cfg: Dict, seed: int, abstract) -> Dict:
 
 
 @functools.cache
-def _jit_state(cfg_json: str):
+def _jit_state(cfg_json: str, shardings):
     cfg = json.loads(cfg_json)
     ref = reference(cfg)
 
@@ -257,7 +323,7 @@ def _jit_state(cfg_json: str):
                         "count": jnp.zeros((), jnp.int32)},
                 "step": jnp.zeros((), jnp.int32)}
 
-    return jax.jit(build)
+    return _jit_placed(build, shardings)
 
 
 def warm_save_shapes(state, ck: Dict) -> None:
@@ -343,6 +409,9 @@ class Run:
         self.fault = fault
         self.ref = reference(cfg)
         self.model, self.rc = program_config(cfg, traffic)
+        self.mesh = make_mesh(cfg)
+        self.batch_sharding = batch_sharding(self.mesh)
+        self.shardings = None        # the state's, once a runtime is built
         self.workdir = tempfile.mkdtemp(prefix="bench_chip_")
         self.ckpt_dir = os.path.join(self.workdir, "ckpt")
         self.counter = CompileCounter()
@@ -354,14 +423,16 @@ class Run:
     def _setup_runtime(self):
         from repro.training.step import abstract_train_state
         rt = new_runtime(self.model, self.rc, self.cfg, self.ckpt_dir,
-                         self.seed)
+                         self.seed, self.mesh)
+        self.shardings = state_shardings(rt)
         prog = rt.dataset.get_batch(0)
         mine = batch(self.cfg, self.traffic, self.seed, 0)
         if not all(np.array_equal(prog[k], mine[k]) for k in mine):
             raise RuntimeError("the program's batch for step 0 differs from "
                                "the benchmark's traffic")
         rt.state = make_state(self.ref, self.cfg, self.seed,
-                              abstract_train_state(self.model, self.rc))
+                              abstract_train_state(self.model, self.rc),
+                              self.shardings)
         self._plant(rt)
         fingerprint(rt.state)
         leaf_norms(rt.state["params"])
@@ -390,21 +461,25 @@ class Run:
         rt.run(n, on_metrics=on_metrics)
         if not save_after:
             self.rec["change_norms"] = change_norms(
-                rt.state["params"], self.ref, self.cfg, self.seed)
+                rt.state["params"], self.ref, self.cfg, self.seed,
+                self.shardings)
 
     def _warm_restored(self, rt) -> None:
         """Compile, in set-up, what the window runs on a restored state:
-        a restore binds host arrays with `jnp.asarray`, which the step
-        and the yardstick functions take as other arguments than the
-        jitted state they have seen."""
+        a restore binds host arrays with `jnp.asarray` (on a mesh, with
+        `device_put` to the state's shardings), which the step and the
+        yardstick functions take as other arguments than the jitted
+        state they have seen."""
         host = jax.device_get(rt.state)
         rt.state = None
         gc.collect()
-        rt.state = jax.tree.map(jnp.asarray, host)
+        rt.state = (jax.tree.map(jnp.asarray, host) if self.shardings is None
+                    else jax.device_put(host, self.shardings))
         del host
         fingerprint(rt.state)
         rt.run(1)
-        change_norms(rt.state["params"], self.ref, self.cfg, self.seed)
+        change_norms(rt.state["params"], self.ref, self.cfg, self.seed,
+                     self.shardings)
 
     # ---- the window: train ---------------------------------------------
     def _train_window(self, rt) -> None:
@@ -476,7 +551,7 @@ class Run:
             sp = Span("rebind")
             ta = time.monotonic()
             rt = new_runtime(self.model, self.rc, self.cfg, self.ckpt_dir,
-                             self.seed)
+                             self.seed, self.mesh)
             self._plant(rt)
             reads: List[Dict] = []
             timed(rt.ckpt, "restore", "restore", reads)
@@ -497,7 +572,8 @@ class Run:
             r["first_step_s"] = td - tc
             r["loss"] = hist[-1]["loss"]
             r["change_norms"] = change_norms(
-                rt.state["params"], self.ref, self.cfg, self.seed)
+                rt.state["params"], self.ref, self.cfg, self.seed,
+                self.shardings)
             free_runtime(rt)
             self.rec["resumes"].append(r)
             if td - t0 >= self.seconds:
@@ -539,8 +615,10 @@ class Run:
         finally:
             if self.trace:
                 jax.profiler.stop_trace()
-        stats = jax.devices()[0].memory_stats() or {}
-        self.rec["memory_peak_bytes"] = stats.get("peak_bytes_in_use")
+        chips = (jax.devices()[:1] if self.mesh is None
+                 else self.mesh.devices.flat)
+        self.rec["memory_peak_bytes"] = [
+            (d.memory_stats() or {}).get("peak_bytes_in_use") for d in chips]
         if tr["kind"] == "train":
             rt.state = None          # the image check needs the device
             gc.collect()
@@ -549,17 +627,19 @@ class Run:
         return self.rec
 
     def _check_image(self, rt) -> None:
-        """Read the newest image back through the program's restore and
-        fingerprint it against the state at its step."""
+        """Read the newest image back through the program's restore (onto
+        the runtime's mesh, where it has one) and fingerprint it against
+        the state at its step."""
         if not self.rec["save_fp"]:
             return
         step = max(self.rec["save_fp"])
         want = self.rec["save_fp"][step]
         self.rec["image_step"] = step
         try:
-            host, _ = rt.ckpt.restore(step)
-            got = fingerprint({"params": host["params"], "opt": host["opt"],
-                               "step": host["step"]})
+            image, _ = rt.ckpt.restore(step, mesh=rt.lower.mesh,
+                                       specs=rt.lower.state_specs)
+            got = fingerprint({"params": image["params"],
+                               "opt": image["opt"], "step": image["step"]})
         except Exception as e:  # an image that cannot be read back is wrong
             print(f"bench: image {step} cannot be read back: {e!r}",
                   file=sys.stderr)

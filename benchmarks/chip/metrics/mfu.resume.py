@@ -1,7 +1,7 @@
-"""A resume's share of the chip's peak: the model FLOPs of the one step
+"""A resume's share of the chips' peak: the model FLOPs of the one step
 it ends with, over the whole resume (restore to the end of that step)
-at the bf16 peak.  The bound on what a kernel's roofline gain on the
-restore path can give."""
+at the cell's chips times the bf16 peak.  The bound on what a kernel's
+roofline gain on the restore path can give."""
 
 
 def read(r):
@@ -9,4 +9,4 @@ def read(r):
     if not res or r["trace"] is None:
         return None
     flops = r["flops_per_token"] * r["tokens_per_step"]
-    return 100.0 * flops / res / r["peaks"]["bf16_flops_per_s"]
+    return 100.0 * flops / res / (r["chips"] * r["peaks"]["bf16_flops_per_s"])

@@ -28,10 +28,10 @@ UNTRACED = "outside any mana span"
 def restore_readings(summary: Dict[str, Dict]) -> Dict[str, float]:
     """Mean per resume of each restore phase, for the `summary()` of a
     recording of resume windows (every file read under a restore):
-    file reads, digest verify, decode (its self time with the chunk
-    joins it holds, codec decode and chain fold), the joins alone, the
-    file reads' rate, the runtime build, the bind, and the bytes sent
-    to the device."""
+    file reads, digest verify, decode (its self time: codec decode and
+    chain fold), the bytes the decode copied because a buffer was
+    read-only, the file reads' rate, the runtime build, the bind, and
+    the bytes sent to the device."""
     n = summary.get("restore", {}).get("count", 0)
     if not n:
         return {}
@@ -44,9 +44,9 @@ def restore_readings(summary: Dict[str, Dict]) -> Dict[str, float]:
         "bytes_read", 0)
     out = {"restore_file_read_s": read_s / n,
            "restore_verify_s": get("ckpt.verify") / n,
-           "restore_decode_s": (get("ckpt.decode", "self_s")
-                                + get("ckpt.join")) / n,
-           "restore_join_s": get("ckpt.join") / n,
+           "restore_decode_s": get("ckpt.decode", "self_s") / n,
+           "decode_copy_bytes": summary.get("ckpt.decode", {}).get(
+               "counts", {}).get("decode_copy_bytes", 0) / n,
            "restore_span_s": get("ckpt.restore") / n,
            "runtime_build_s": get("runtime.build") / n,
            "restore_bind_s": get("restore.bind") / n,

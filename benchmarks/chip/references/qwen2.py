@@ -1,4 +1,5 @@
-"""Plain reference of a Qwen2 decoder (arXiv:2407.10671) for training.
+"""The Qwen2 family (arXiv:2407.10671): its plain training reference,
+the program's model settings for it, and its operation counts.
 
 Straightforward jax.numpy in float32 with every matmul at
 `Precision.HIGHEST`: RMSNorm, rotary embeddings (rotate-half), grouped
@@ -18,6 +19,12 @@ Departures, each because of the program's stored layout, not its maths:
 `quant="fp8"` is the precision control: every matmul operand is first
 rounded to float8_e4m3fn with a per-tensor scale (straight-through
 gradient), the step below the bfloat16 compute the configuration states.
+
+A family module gives the harness all that is family-specific, found by
+the configuration's `reference` key: `param_shapes`, `init_params` and
+`loss` (the reference), `program_model` (the configuration's keys in
+the program's terms) and `train_flops_per_token` (the yardstick's
+count).  A new family is this file and a configuration naming it.
 """
 from __future__ import annotations
 
@@ -42,6 +49,40 @@ def layout(cfg: Dict) -> Dict[str, int]:
     return {"d": d, "hd": d // h, "k": k, "g": g, "kp": kp, "gp": gp,
             "f": cfg["intermediate_size"], "v": cfg["vocab_size"],
             "l": cfg["num_hidden_layers"]}
+
+
+def program_model(cfg: Dict) -> Dict:
+    """The program's model settings (`repro.configs.base.ModelConfig`
+    keywords) for a configuration, as plain values."""
+    x = layout(cfg)
+    return {"family": "dense", "n_layers": x["l"], "d_model": x["d"],
+            "n_heads": cfg["num_attention_heads"], "n_kv_heads": x["k"],
+            "d_ff": x["f"], "vocab_size": x["v"], "head_dim": x["hd"],
+            "qkv_bias": cfg["layout"]["qkv_bias"],
+            "rope_theta": cfg["rope_theta"], "norm_eps": cfg["rms_norm_eps"],
+            "tie_embeddings": cfg["tie_word_embeddings"],
+            "pad_to": cfg["layout"]["head_pad_to"]}
+
+
+def matmul_params(cfg: Dict) -> int:
+    """Parameters that enter a matmul once per token, at published sizes
+    (padded heads left out), the tied LM head included."""
+    x = layout(cfg)
+    d, hd, h = x["d"], x["hd"], cfg["num_attention_heads"]
+    per_layer = (2 * d * h * hd               # q and o projections
+                 + 2 * d * x["k"] * hd        # k and v projections
+                 + 3 * d * x["f"])            # gated MLP
+    return x["l"] * per_layer + x["v"] * d    # + LM head
+
+
+def train_flops_per_token(cfg: Dict, seq_len: int) -> float:
+    """Forward and backward matmul FLOPs of one token: 6 x the matmul
+    parameters, plus causal attention at half the square (QK^T and PV,
+    2 FLOPs per multiply-add).  Rematerialised work does not count."""
+    x = layout(cfg)
+    h = cfg["num_attention_heads"]
+    attn_fwd = x["l"] * 2 * 2 * (seq_len / 2) * h * x["hd"]
+    return 3.0 * (2.0 * matmul_params(cfg) + attn_fwd)
 
 
 def param_shapes(cfg: Dict) -> Dict:

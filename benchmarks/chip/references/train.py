@@ -72,21 +72,34 @@ def _step(ref, cfg, quant, params, m, v, tokens, labels, count, lr):
 
 
 def follow(ref, cfg: Dict, seed: int, batches: List[Dict[str, np.ndarray]],
-           quant: Optional[str] = None, rows: Optional[int] = None) -> Dict:
+           quant: Optional[str] = None, rows: Optional[int] = None,
+           shardings: Optional[Dict] = None, batch_sharding=None) -> Dict:
     """Run len(batches) reference steps from the seed's weights.
 
     `rows` keeps only the first rows of every batch: the half-batch
-    fault, put in the program's place."""
+    fault, put in the program's place.  `shardings`, a state's tree of
+    `NamedSharding`s ({"params", "opt": {"m", "v"}, ...}), places the
+    weights and moments over several chips through the jitted calls'
+    output shardings, and `batch_sharding` the rows of each batch; the
+    equations are the same."""
     frozen = _Frozen(cfg)
-    init = jax.jit(ref.init_params, static_argnums=0)
+    p_sh = mv_sh = whole = None          # None: placed as jit chooses
+    if shardings is not None:
+        p_sh, mv_sh = shardings["params"], shardings["opt"]["m"]
+        whole = shardings["step"]
+    init = jax.jit(ref.init_params, static_argnums=0, out_shardings=p_sh)
     params = init(frozen, np.uint32(seed))
-    m = jax.tree.map(jnp.zeros_like, params)
-    v = jax.tree.map(jnp.zeros_like, params)
+    zeros = jax.jit(lambda p: jax.tree.map(jnp.zeros_like, p),
+                    out_shardings=mv_sh)
+    m, v = zeros(params), zeros(params)
     step = jax.jit(functools.partial(_step, ref, frozen, quant),
-                   donate_argnums=(0, 1, 2))
+                   donate_argnums=(0, 1, 2),
+                   out_shardings=(p_sh, mv_sh, mv_sh, whole, whole))
     losses, grad_norms = [], None
     for i, b in enumerate(batches):
         tok, lab = b["tokens"][:rows], b["labels"][:rows]
+        if batch_sharding is not None:
+            tok, lab = jax.device_put((tok, lab), batch_sharding)
         params, m, v, loss, gn = step(
             params, m, v, jnp.asarray(tok), jnp.asarray(lab),
             jnp.float32(i + 1), jnp.float32(lr_at(i, cfg["run"]["optimizer"])))

@@ -36,7 +36,9 @@ def parse(argv):
 
 
 def cell_files(name: str, root: str = ROOT):
-    """(cell, configuration, traffic, benchmark) for a cell name."""
+    """(cell, configuration, traffic, benchmark) for a cell name.  A
+    configuration whose family module lacks a function, or whose mesh
+    is not of the cell's chips, is refused."""
     sys.path.insert(0, HERE)
     import harness
     with open(os.path.join(root, "BENCHMARK.json")) as f:
@@ -46,6 +48,11 @@ def cell_files(name: str, root: str = ROOT):
         raise SystemExit(f"unknown workload {name!r}; known: {sorted(cells)}")
     cell = cells[name]
     cfg = harness.load_json("configs", cell["config"] + ".json")
+    harness.reference(cfg)
+    if harness.mesh_chips(cfg) != cell["chips"]:
+        raise SystemExit(f"{name} asks for {cell['chips']} chip(s), and the "
+                         f"mesh of {cfg['name']} holds "
+                         f"{harness.mesh_chips(cfg)}")
     traffic = dict(harness.load_json("traffic", cell["traffic"] + ".json"),
                    name=cell["traffic"])
     return cell, cfg, traffic, bench
@@ -83,7 +90,9 @@ def checks(run, rec: dict, cfg: dict, traffic: dict) -> tuple:
     import harness
     n = traffic["setup_steps"] + (1 if traffic["kind"] == "resume" else 0)
     batches = [harness.batch(cfg, traffic, run.seed, i) for i in range(n)]
-    ref = harness.train_reference().follow(run.ref, cfg, run.seed, batches)
+    ref = harness.train_reference().follow(
+        run.ref, cfg, run.seed, batches, shardings=run.shardings,
+        batch_sharding=run.batch_sharding)
     if traffic["kind"] == "resume":
         numbers = {}
         for r in rec["resumes"] or [None]:
@@ -121,12 +130,13 @@ def checks(run, rec: dict, cfg: dict, traffic: dict) -> tuple:
 
 
 def per_layer(rec: dict, wanted, trace_summary, device_kind: str,
-              cfg: dict, traffic: dict) -> dict:
+              cfg: dict, traffic: dict, chips: int) -> dict:
     import counts
     import harness
     r = dict(rec)
     r["trace"] = trace_summary
     r["peaks"] = harness.peaks(device_kind)
+    r["chips"] = chips
     r["flops_per_token"] = counts.train_flops_per_token(cfg,
                                                         traffic["seq_len"])
     r["tokens_per_step"] = traffic["batch"] * traffic["seq_len"]
@@ -173,9 +183,11 @@ def run_cell(args, require_tpu: bool = True, cfg_override=None,
         ok, judged, failed, extra = checks(run, rec, cfg, traffic)
     finally:
         run.cleanup()
+    peaks = [p for p in rec["memory_peak_bytes"] if p is not None]
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": cell["chips"],
-              "memory_peak_bytes": rec["memory_peak_bytes"]}
+              "memory_peak_bytes": max(peaks) if peaks else None}
+    extra["memory_peak_bytes_per_chip"] = rec["memory_peak_bytes"]
     result = {"correct": ok,
               "attempted": max(len(rec["steps"]) - 1, 0) + len(rec["saves"])
               + len(rec["resumes"]),
@@ -183,8 +195,12 @@ def run_cell(args, require_tpu: bool = True, cfg_override=None,
     if args.trace:
         result["metrics"] = per_layer(rec, metrics_for(bench, cell["name"],
                                                        True),
-                                      summary, dev.device_kind, cfg, traffic)
+                                      summary, dev.device_kind, cfg, traffic,
+                                      cell["chips"])
         device.update(busy_s=summary["busy_s"], window_s=summary["window_s"])
+        extra["device_idle_share_per_chip"] = [
+            100.0 * (1.0 - b / summary["window_s"])
+            for b in summary["busy_s_per_chip"]]
         result["breakdown"] = summary["breakdown"]
     else:
         e2e = end_to_end(rec, traffic["batch"] * traffic["seq_len"])

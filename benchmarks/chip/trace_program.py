@@ -93,7 +93,7 @@ def measure(args, require_tpu: bool = True, cfg_override=None,
             summary = traces.reduce(pd, kernels=harness.KERNELS)
             out["metrics"] = bench.per_layer(
                 rec, bench.metrics_for(bch, cell["name"], True), summary,
-                dev.device_kind, cfg, traffic)
+                dev.device_kind, cfg, traffic, cell["chips"])
             out["breakdown"] = summary["breakdown"]
             out["busy_s"], out["window_s"] = (summary["busy_s"],
                                               summary["window_s"])

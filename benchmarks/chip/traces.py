@@ -24,11 +24,11 @@ def load(path: str):
 
 
 def device_events(pd) -> List[List[Tuple[float, float, str]]]:
-    """Per device plane: [(start_ns, end_ns, HLO text)] of XLA ops."""
+    """Per device plane, in the order of the chips' ids: [(start_ns,
+    end_ns, HLO text)] of XLA ops."""
     out = []
-    for plane in pd.planes:
-        if not DEVICE_PLANE.match(plane.name):
-            continue
+    planes = [p for p in pd.planes if DEVICE_PLANE.match(p.name)]
+    for plane in sorted(planes, key=lambda p: int(p.name.rsplit(":", 1)[1])):
         evs = []
         for line in plane.lines:
             if line.name != OPS_LINE:
@@ -90,8 +90,9 @@ def operand_bytes(hlo: str) -> Optional[int]:
 def reduce(pd, window: Optional[Tuple[float, float]] = None,
            kernels: Iterable[str] = (), top: int = 10) -> Dict:
     """Busy and idle time inside `window` (ns; default: the span named
-    `bench.window`), the time and bytes of each named kernel over the
-    whole trace, and the breakdown the result line carries."""
+    `bench.window`), per chip and averaged over the chips; the time and
+    bytes of each named kernel over the whole trace, summed over the
+    chips; and the breakdown the result line carries (chip 0's)."""
     planes = device_events(pd)
     spans = host_spans(pd)
     if window is None:
@@ -139,6 +140,7 @@ def reduce(pd, window: Optional[Tuple[float, float]] = None,
     window_s = (hi - lo) / 1e9
     return {
         "busy_s": sum(busy) / len(busy) / 1e9,
+        "busy_s_per_chip": [b / 1e9 for b in busy],
         "window_s": window_s,
         "kernels": kern,
         "breakdown": {
