@@ -121,6 +121,10 @@ def train_and_save(cfg, rc, ckpt_dir, *, mesh=None, every=2, saves=2,
             jax.block_until_ready(rt.state)
             marks.append((time.monotonic(), rt.checkpoints_taken,
                           rt.ckpt.writing(), len(rec.compiles())))
+            if "moe_tokens_held" in m:   # a dropless expert layer's counters
+                print(f"smoke: step {step} moe_tokens_held "
+                      f"{m['moe_tokens_held']:.0f} moe_max_load "
+                      f"{m['moe_max_load']:.3f}", flush=True)
 
         c0, t0 = len(rec.compiles()), time.monotonic()
         rt.run(1, on_metrics=on_metrics)

@@ -25,6 +25,7 @@ from repro.configs.qwen1_5_0_5b import CONFIG as QWEN1_5_0_5B
 from repro.configs.hymba_1_5b import CONFIG as HYMBA_1_5B
 from repro.configs.llama32_vision_11b import CONFIG as LLAMA32_VISION_11B
 from repro.configs.rwkv6_3b import CONFIG as RWKV6_3B
+from repro.configs.moonlight_16b_a3b import CONFIG as MOONLIGHT_16B_A3B
 
 ARCHS = {
     c.arch_id: c
@@ -39,6 +40,7 @@ ARCHS = {
         HYMBA_1_5B,
         LLAMA32_VISION_11B,
         RWKV6_3B,
+        MOONLIGHT_16B_A3B,
     )
 }
 
@@ -79,7 +81,14 @@ def reduced_config(cfg: ModelConfig, **overrides) -> ModelConfig:
         cross_attn_every=2 if cfg.cross_attn_every else 0,
         ssm_state=8 if cfg.ssm_state else 0,
     )
-    if cfg.moe is not None:
+    if cfg.moe is not None and cfg.moe.router == "deepseek_v3":
+        # 8 routed experts, 4 of them held here, top-3: the share's split
+        small["moe"] = dataclasses.replace(cfg.moe, num_experts=8, top_k=3,
+                                           d_expert=32, held=4)
+    elif cfg.moe is not None:
         small["moe"] = MoEConfig(num_experts=4, top_k=2, capacity_factor=1.5)
+    if cfg.kv_lora_rank:
+        small.update(head_dim=24, kv_lora_rank=32, qk_nope_dim=16,
+                     qk_rope_dim=8, v_head_dim=16, n_kv_heads=n_heads)
     small.update(overrides)
     return dataclasses.replace(cfg, **small)

@@ -18,10 +18,33 @@ from typing import Optional, Tuple
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int
+    num_experts: int      # the router's width: experts a token may pick
     top_k: int
     # Capacity factor for the GShard-style dispatch einsum.
     capacity_factor: float = 1.25
+
+    # The routing family (`repro.models.moe` owns the choice): "gshard"
+    # (softmax, the capacity-bound dispatch above) or "deepseek_v3"
+    # (sigmoid scores, the top-k renormalised and scaled, shared experts,
+    # a balance loss, and every token-expert assignment computed: sorted
+    # by expert and run as grouped matmuls, `jax.lax.ragged_dot`; below).
+    router: str = "gshard"
+    d_expert: int = 0             # an expert's width
+    n_shared: int = 0             # shared experts, run as one MLP
+    routed_scale: float = 1.0     # the routed output's scaling factor
+    # the experts this chip holds of an expert-parallel layer: ids
+    # [first_held, first_held + held) of the router's num_experts (0: all)
+    held: int = 0
+    first_held: int = 0
+    balance_alpha: float = 0.0    # sequence-wise balance loss weight
+
+    def __post_init__(self):
+        if self.router not in ("gshard", "deepseek_v3"):
+            raise ValueError(f"unknown MoE router {self.router!r}")
+
+    @property
+    def n_held(self) -> int:
+        return self.held or self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -44,6 +67,14 @@ class ModelConfig:
 
     # --- MoE ---
     moe: Optional[MoEConfig] = None
+    n_dense_layers: int = 0           # leading dense layers before the experts
+
+    # --- multi-head latent attention (MLA; kv_lora_rank > 0 enables) ---
+    # head_dim is then qk_nope_dim + qk_rope_dim, the q/k head size
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
 
     # --- hybrid (hymba): parallel attention + mamba heads ---
     ssm_state: int = 0                # >0 enables the parallel mamba path
@@ -75,6 +106,8 @@ class ModelConfig:
     pad_to: int = 16
 
     def __post_init__(self):
+        if isinstance(self.moe, dict):   # plain values, e.g. from JSON
+            object.__setattr__(self, "moe", MoEConfig(**self.moe))
         if self.head_dim == 0 and self.n_heads:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
 
@@ -144,14 +177,19 @@ class ModelConfig:
         head = 0 if self.tie_embeddings else V * d
 
         def attn_params() -> int:
+            if self.kv_lora_rank:
+                r, h = self.kv_lora_rank, self.n_heads
+                return (d * self.q_dim + d * (r + self.qk_rope_dim) + r
+                        + r * h * (self.qk_nope_dim + self.v_head_dim)
+                        + h * self.v_head_dim * d)
             p = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
             if self.qkv_bias:
                 p += self.q_dim + 2 * self.kv_dim
             return p
 
-        def mlp_params(n_copies: float = 1.0) -> int:
+        def mlp_params(n_copies: float = 1.0, width: int = dff) -> int:
             # gated MLP (SwiGLU-style): 3 matrices
-            return int(3 * d * dff * n_copies)
+            return int(3 * d * width * n_copies)
 
         per_layer = 0
         if self.rwkv:
@@ -159,9 +197,17 @@ class ModelConfig:
             per_layer = 5 * d * d + 2 * d * dff
         else:
             per_layer += attn_params()
-            if self.moe is not None:
-                n = (self.moe.top_k if active_only else self.moe.num_experts)
-                per_layer += mlp_params(n) + d * self.moe.num_experts  # + router
+            moe = self.moe
+            if moe is not None and moe.router == "deepseek_v3":
+                # the held experts (active: top_k x held / num_experts)
+                held = moe.n_held
+                n = moe.top_k * held / moe.num_experts if active_only else held
+                per_layer += (mlp_params(n, moe.d_expert)
+                              + mlp_params(moe.n_shared, moe.d_expert)
+                              + d * moe.num_experts)
+            elif moe is not None:
+                n = (moe.top_k if active_only else moe.num_experts)
+                per_layer += mlp_params(n) + d * moe.num_experts  # + router
             else:
                 per_layer += mlp_params()
             if self.ssm_state > 0:
@@ -169,6 +215,9 @@ class ModelConfig:
                 # in_proj (x,z), dt/B/C proj, out_proj, conv
                 per_layer += d * 2 * d_in + d_in * (2 * self.ssm_state + 2) + d_in * d + 4 * d_in
         total = embed + head + self.n_layers * per_layer
+        if self.n_dense_layers:
+            dense = attn_params() + mlp_params()
+            total += self.n_dense_layers * (dense - per_layer)
 
         if self.enc_dec:
             enc_per = attn_params() + mlp_params()

@@ -70,8 +70,18 @@ class LowerHalf:
         # lower half's fabric (repro.comm.transport.faults) — physical
         # state like the rest of the comm world, never checkpointed
         comm = create_world(transport, n_ranks, fault_plan=fault_plan)
+        # On an accelerator the step writes its output state into the
+        # buffers of the state it was given (donated), so device memory
+        # holds one state and not two.  The safe point's snapshot is a
+        # finished host copy (`ckpt._to_host`) before the next step runs.
+        # On the CPU, memory is not the bound, and a caller may still
+        # read a state it passed in.
+        platform = (jax.default_backend() if mesh is None
+                    else mesh.devices.flat[0].platform)
+        donate = () if platform == "cpu" else (0,)
         if mesh is None:
-            return cls(None, None, jax.jit(make_train_step(cfg, rc, None)),
+            return cls(None, None, jax.jit(make_train_step(cfg, rc, None),
+                                           donate_argnums=donate),
                        None, comm, transport)
         rules = ShardingRules(mesh, moe_mode=rc.moe_mode,
                               seq_shard=rc.seq_shard,
@@ -86,7 +96,8 @@ class LowerHalf:
 
         step = jax.jit(make_train_step(cfg, rc, rules),
                        in_shardings=(shard(specs), None),
-                       out_shardings=(shard(specs), None))
+                       out_shardings=(shard(specs), None),
+                       donate_argnums=donate)
         return cls(mesh, rules, step, specs, comm, transport)
 
 

@@ -107,7 +107,7 @@ def _flash_fwd_impl(q, k, v, causal: bool, chunk: int):
     T = k.shape[1]
     n = T // chunk
     kb = k.reshape(B, n, chunk, K, hd).swapaxes(0, 1)
-    vb = v.reshape(B, n, chunk, K, hd).swapaxes(0, 1)
+    vb = v.reshape(B, n, chunk, K, v.shape[-1]).swapaxes(0, 1)
 
     def body(carry, xs):
         m, l, acc = carry
@@ -131,7 +131,7 @@ def _flash_fwd_impl(q, k, v, causal: bool, chunk: int):
 
     m0 = jnp.full((B, S, K, G), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, S, K, G), jnp.float32)
-    a0 = jnp.zeros((B, S, K, G, hd), jnp.float32)
+    a0 = jnp.zeros((B, S, K, G, v.shape[-1]), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0), (kb, vb, jnp.arange(n)))
     lse = m + jnp.log(jnp.maximum(l, 1e-30))
     out = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
@@ -155,7 +155,7 @@ def _flash_bwd(causal, chunk, res, dout):
     T = k.shape[1]
     n = T // chunk
     kb = k.reshape(B, n, chunk, K, hd).swapaxes(0, 1)
-    vb = v.reshape(B, n, chunk, K, hd).swapaxes(0, 1)
+    vb = v.reshape(B, n, chunk, K, v.shape[-1]).swapaxes(0, 1)
     dout_f = dout.astype(jnp.float32)
     # delta = rowsum(dout * out)
     delta = jnp.sum(dout_f * out.astype(jnp.float32), axis=-1)  # (B,S,K,G)
@@ -182,7 +182,7 @@ def _flash_bwd(causal, chunk, res, dout):
     dq0 = jnp.zeros((B, S, K, G, hd), jnp.float32)
     dq, (dkb, dvb) = jax.lax.scan(body, dq0, (kb, vb, jnp.arange(n)))
     dk = dkb.swapaxes(0, 1).reshape(B, T, K, hd).astype(k.dtype)
-    dv = dvb.swapaxes(0, 1).reshape(B, T, K, hd).astype(v.dtype)
+    dv = dvb.swapaxes(0, 1).reshape(B, T, K, v.shape[-1]).astype(v.dtype)
     return dq.astype(q.dtype), dk, dv
 
 
@@ -198,7 +198,8 @@ def _fit_chunk(total: int, chunk: int) -> int:
 
 
 def flash_attention(q, k, v, *, causal: bool, chunk: int = 128):
-    """q: (B,S,H,hd); k,v: (B,T,K,hd)."""
+    """q: (B,S,H,hd); k: (B,T,K,hd); v: (B,T,K,hv) -> (B,S,H,hv).  The
+    softmax scale is hd ** -0.5 (hv may differ from hd, as in MLA)."""
     B, S, H, hd = q.shape
     K = k.shape[2]
     T = k.shape[1]
@@ -206,7 +207,7 @@ def flash_attention(q, k, v, *, causal: bool, chunk: int = 128):
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32)).astype(q.dtype)
     qg = _group(q * scale, K)
     o = _flash(qg, k, v, causal, chunk)
-    return o.reshape(B, S, H, hd)
+    return o.reshape(B, S, H, v.shape[-1])
 
 
 # ==========================================================================

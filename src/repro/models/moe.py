@@ -1,4 +1,5 @@
-"""Mixture-of-Experts: top-k routing with GShard-style dispatch einsums.
+"""Mixture-of-Experts: top-k routing with GShard-style dispatch einsums,
+and dropless routing over an expert-parallel share (`dropless_moe_apply`).
 
 Expert parallelism under a fixed (data, model) mesh: expert weights are
 stored in a *virtual-expert* layout — each real expert's gated-MLP is
@@ -20,7 +21,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import _dense_init
+from repro.models.layers import _dense_init, init_mlp, mlp_apply
 
 
 def init_moe(key, d_model: int, d_ff: int, num_experts: int, split: int):
@@ -121,3 +122,203 @@ def moe_apply(p, x, *, num_experts: int, top_k: int, split: int,
         yout = wsc(yout, rules.named(("batch", "expert", None, None)))
     y = jnp.einsum("gtec,gecd->gtd", combine, yout)             # all-reduce(model)
     return y.reshape(B, S, d), {"moe_aux": aux_loss}
+
+
+# ==========================================================================
+# Dropless routing over the experts this chip holds (DeepSeek-V3 style)
+# ==========================================================================
+#
+# The router keeps its full width and its top-k; the layer holds experts
+# [first_held, first_held + held) of an expert-parallel layer and computes
+# their part of the result for the tokens routed to them.  What the
+# absent experts would add is not computed: on one chip there is no
+# exchange, and nothing stands in for it.  Every assignment to a held
+# expert is computed (no capacity, no dropped token): the token-expert
+# assignments are sorted by expert and the held experts run as grouped
+# matmuls over their contiguous rows (`jax.lax.ragged_dot`).
+
+
+def init_dropless_moe(key, d_model: int, d_expert: int, num_experts: int,
+                      held: int, n_shared: int):
+    ks = jax.random.split(key, 5)
+    params = {
+        "router": _dense_init(ks[0], (d_model, num_experts)),
+        "wi": _dense_init(ks[1], (held, d_model, d_expert), in_axis=1),
+        "wg": _dense_init(ks[2], (held, d_model, d_expert), in_axis=1),
+        "wo": _dense_init(ks[3], (held, d_expert, d_model), in_axis=1),
+    }
+    logical = {
+        "router": (None, None),
+        "wi": ("expert_held", None, None),
+        "wg": ("expert_held", None, None),
+        "wo": ("expert_held", None, None),
+    }
+    if n_shared:
+        # the shared experts as one MLP n_shared times an expert's width
+        params["shared"], logical["shared"] = init_mlp(
+            ks[4], d_model, n_shared * d_expert)
+    return params, logical
+
+
+def route(logits, moe):
+    """f32 router logits (..., E) -> (weights (..., k), expert ids
+    (..., k), scores (..., E)).  Scores are the sigmoid of the logits;
+    the top-k of the scores are chosen (the selection bias held at
+    zero), renormalised and scaled."""
+    scores = jax.nn.sigmoid(logits)
+    w, idx = _topk_by_argmax(scores, moe.top_k)
+    w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+    return w * moe.routed_scale, idx, scores
+
+
+def balance_loss(scores, idx, num_experts: int, top_k: int):
+    """DeepSeek-V3's sequence-wise balance loss (arXiv:2412.19437,
+    2.1.2), averaged over the sequences: sum_i f_i P_i with
+    f_i = E / (k T) * (tokens of the sequence that chose expert i) and
+    P_i the mean over its T tokens of the scores normalised over the E
+    experts.  scores: (B, T, E); idx: (B, T, k)."""
+    T = scores.shape[1]
+    p = (scores / scores.sum(axis=-1, keepdims=True)).mean(axis=1)
+    chosen = jax.nn.one_hot(idx, num_experts, dtype=jnp.float32)
+    f = chosen.sum(axis=(1, 2)) * (num_experts / (top_k * T))
+    return jnp.mean(jnp.sum(f * p, axis=-1))
+
+
+@jax.custom_vjp
+def _permute(x, perm, inv):
+    """x[perm] for a permutation of rows; the gradient is a gather by
+    the inverse permutation, not a scatter."""
+    return x[perm]
+
+
+def _permute_fwd(x, perm, inv):
+    return x[perm], inv
+
+
+def _permute_bwd(inv, g):
+    return g[inv], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+@jax.custom_vjp
+def _dispatch(x, order, inv):
+    """Row a of the result is x[order[a] // k], k = rows / len(x): each
+    token's k assignments, in the sorted order.  The gradient gathers
+    each token's k rows back by the inverse order and sums them."""
+    k = order.shape[0] // x.shape[0]
+    return x[order // k]
+
+
+def _dispatch_fwd(x, order, inv):
+    k = order.shape[0] // x.shape[0]
+    return x[order // k], (order, inv, x.shape[0])
+
+
+def _dispatch_bwd(res, g):
+    order, inv, n = res
+    k = order.shape[0] // n
+    # the sum over a token's k rows as a contraction: accumulated in
+    # f32 without an f32 copy of the (n * k, d) rows
+    dx = jnp.einsum("nkd,k->nd", g[inv].reshape(n, k, -1),
+                    jnp.ones((k,), g.dtype),
+                    preferred_element_type=jnp.float32)
+    return dx.astype(g.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+def held_experts(p, x, w, idx, moe):
+    """The held experts' part of the routed output.  x: (N, d); w, idx:
+    (N, k) weights and expert ids over the router's experts.  Returns
+    (y (N, d), per-layer counters)."""
+    N, d = x.shape
+    k = idx.shape[-1]
+    local = idx - moe.first_held
+    held = (local >= 0) & (local < moe.n_held)
+    key = jnp.where(held, local, moe.n_held).reshape(N * k)
+    order = jnp.argsort(key, stable=True)
+    inv = jnp.argsort(order)
+    sizes = jnp.sum(key[:, None] == jnp.arange(moe.n_held), axis=0,
+                    dtype=jnp.int32)
+    total = jnp.sum(sizes)
+    # rows past the held assignments belong to no group: zero them, in
+    # the results and (through `where`) in every gradient
+    valid = (jnp.arange(N * k) < total)[:, None]
+
+    def gdot(a, wt):
+        return jnp.where(valid, jax.lax.ragged_dot(a, wt.astype(a.dtype),
+                                                   sizes), 0)
+
+    xs = jnp.where(valid, _dispatch(x, order, inv), 0)
+    h = gdot(xs, p["wg"])
+    u = gdot(xs, p["wi"])
+    ys = gdot(jax.nn.silu(h) * u, p["wo"])
+    ya = _permute(ys, inv, order).reshape(N, k, d)
+    # gate-weighted sum over a token's k assignments, a contraction in
+    # the compute dtype (the gates rounded to it like the expert outputs):
+    # no f32 copy of the (N, k, d) rows forward or backward
+    wh = jnp.where(held, w, 0.0).astype(x.dtype)
+    y = jnp.einsum("nkd,nk->nd", ya, wh)
+    sizes_f = sizes.astype(jnp.float32)
+    stats = {"tokens_held": total.astype(jnp.float32),
+             "max_load": jnp.max(sizes_f) / jnp.maximum(
+                 jnp.mean(sizes_f), 1e-30)}
+    return y, stats
+
+
+def dropless_moe_apply(p, x, moe):
+    """x: (B, S, d) -> (B, S, d), {moe_aux (the balance loss),
+    tokens_held, max_load}.  Router logits in float32."""
+    B, S, d = x.shape
+    with jax.named_scope("moe.router"):
+        logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
+                            p["router"],
+                            precision=jax.lax.Precision.HIGHEST)
+        w, idx, scores = route(logits, moe)
+        aux = balance_loss(scores, idx, moe.num_experts, moe.top_k)
+    with jax.named_scope("moe.experts"):
+        y, stats = held_experts(p, x.reshape(B * S, d),
+                                w.reshape(B * S, -1), idx.reshape(B * S, -1),
+                                moe)
+        y = y.reshape(B, S, d)
+    if "shared" in p:
+        with jax.named_scope("moe.shared"):
+            y = y + mlp_apply(p["shared"], x)
+    return y, {"moe_aux": aux, **stats}
+
+
+# ---------------------------------------------------------------------------
+# the routing family a configuration names (`MoEConfig.router`)
+# ---------------------------------------------------------------------------
+
+def init_layer(key, cfg, split: int):
+    """An expert layer's (params, logical) for a ModelConfig; `split` is
+    the GShard layout's virtual-expert split."""
+    m = cfg.moe
+    if m.router == "deepseek_v3":
+        return init_dropless_moe(key, cfg.d_model, m.d_expert, m.num_experts,
+                                 m.n_held, m.n_shared)
+    return init_moe(key, cfg.d_model, cfg.d_ff, m.num_experts, split)
+
+
+def apply_layer(p, x, cfg, split: int, rules=None):
+    """x: (B, S, d) -> (B, S, d), aux dict (`moe_aux`, and a dropless
+    layer's counters)."""
+    m = cfg.moe
+    if m.router == "deepseek_v3":
+        return dropless_moe_apply(p, x, m)
+    return moe_apply(p, x, num_experts=m.num_experts, top_k=m.top_k,
+                     split=split, capacity_factor=m.capacity_factor,
+                     rules=rules)
+
+
+def objective(cfg, moe_aux):
+    """The term the expert layers add to the loss, from `moe_aux` summed
+    over them: DeepSeek-V3's balance loss times its alpha, or GShard's
+    load loss at 0.01 a layer."""
+    if cfg.moe.router == "deepseek_v3":
+        return cfg.moe.balance_alpha * moe_aux
+    return 0.01 * moe_aux / cfg.n_layers

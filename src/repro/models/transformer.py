@@ -21,6 +21,7 @@ from repro.configs.base import ModelConfig, RunConfig, ShapeConfig
 from repro.models import attention as attn
 from repro.models import layers as L
 from repro.models import mamba as mam
+from repro.models import mla
 from repro.models import moe as moe_mod
 from repro.models import rwkv as rwkv_mod
 
@@ -30,14 +31,22 @@ from repro.models import rwkv as rwkv_mod
 # ==========================================================================
 
 
-def _init_dense_block(key, cfg: ModelConfig, cross: bool):
+def _init_dense_block(key, cfg: ModelConfig, cross: bool,
+                      dense_ffn: bool = False):
+    """One block; `dense_ffn` gives a leading dense layer of an MoE model
+    its MLP."""
     ks = jax.random.split(key, 6)
     params: Dict[str, Any] = {"ln1": L._norm_init((cfg.d_model,)),
                               "ln2": L._norm_init((cfg.d_model,))}
     logical: Dict[str, Any] = {"ln1": (None,), "ln2": (None,)}
-    params["attn"], logical["attn"] = attn.init_attention(
-        ks[0], cfg.d_model, cfg.n_heads_padded, cfg.n_kv_heads_padded,
-        cfg.head_dim, cfg.qkv_bias)
+    if cfg.kv_lora_rank:
+        params["attn"], logical["attn"] = mla.init_mla(
+            ks[0], cfg.d_model, cfg.n_heads, cfg.kv_lora_rank,
+            cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim)
+    else:
+        params["attn"], logical["attn"] = attn.init_attention(
+            ks[0], cfg.d_model, cfg.n_heads_padded, cfg.n_kv_heads_padded,
+            cfg.head_dim, cfg.qkv_bias)
     if cfg.ssm_state:
         params["mamba"], logical["mamba"] = mam.init_mamba(
             ks[1], cfg.d_model, cfg.ssm_state, cfg.ssm_expand)
@@ -47,11 +56,11 @@ def _init_dense_block(key, cfg: ModelConfig, cross: bool):
         params["xattn"], logical["xattn"] = attn.init_attention(
             ks[2], cfg.d_model, cfg.n_heads_padded, cfg.n_kv_heads_padded,
             cfg.head_dim)
-    if cfg.moe is not None:
-        params["moe"], logical["moe"] = moe_mod.init_moe(
-            ks[3], cfg.d_model, cfg.d_ff, cfg.moe.num_experts, moe_split(cfg))
-    else:
+    if cfg.moe is None or dense_ffn:
         params["mlp"], logical["mlp"] = L.init_mlp(ks[3], cfg.d_model, cfg.d_ff)
+    else:
+        params["moe"], logical["moe"] = moe_mod.init_layer(
+            ks[3], cfg, moe_split(cfg))
     return params, logical
 
 
@@ -118,7 +127,14 @@ def init_params(cfg: ModelConfig, key) -> Tuple[Dict, Dict]:
         params["cross_blocks"], logical["cross_blocks"] = _stack_init(
             lambda k: _init_dense_block(k, cfg, cross=True), ckeys)
     else:
-        bkeys = jax.random.split(keys[1], cfg.n_layers)
+        if cfg.n_dense_layers:
+            # leading dense layers: a stack (and a scan) of their own, as
+            # their leaves differ in shape from the expert layers'
+            dkeys = jax.random.split(keys[4], cfg.n_dense_layers)
+            params["dense_blocks"], logical["dense_blocks"] = _stack_init(
+                lambda k: _init_dense_block(k, cfg, cross=cfg.enc_dec,
+                                            dense_ffn=True), dkeys)
+        bkeys = jax.random.split(keys[1], cfg.n_layers - cfg.n_dense_layers)
         params["blocks"], logical["blocks"] = _stack_init(
             lambda k: _init_dense_block(k, cfg, cross=cfg.enc_dec), bkeys)
 
@@ -138,6 +154,10 @@ def init_params(cfg: ModelConfig, key) -> Tuple[Dict, Dict]:
 
 def _self_attention_seq(cfg: ModelConfig, rc: RunConfig, p, h, positions,
                         causal: bool):
+    if cfg.kv_lora_rank:
+        # no kv cache is kept: MLA has no prefill / decode here
+        return mla.mla_attention(p, h, cfg, positions,
+                                 chunk=rc.attn_chunk), (None, None)
     q, k, v = attn.qkv_proj(p, h, cfg.rope_theta, positions)
     S = h.shape[1]
     if cfg.sliding_window and causal and cfg.sliding_window < S:
@@ -185,10 +205,7 @@ def _mixer_block_seq(cfg, rc, rules, p, x, positions, enc_out, causal=True):
         cache["xk"], cache["xv"] = ck, cv
     h2 = L.rms_norm(x, p["ln2"], cfg.norm_eps)
     if "moe" in p:
-        y, aux = moe_mod.moe_apply(
-            p["moe"], h2, num_experts=cfg.moe.num_experts, top_k=cfg.moe.top_k,
-            split=moe_split(cfg), capacity_factor=cfg.moe.capacity_factor,
-            rules=rules)
+        y, aux = moe_mod.apply_layer(p["moe"], h2, cfg, moe_split(cfg), rules)
     else:
         y = L.mlp_apply(p["mlp"], h2)
     y = _ckpt_name(y, "mlp_out")
@@ -311,12 +328,22 @@ def forward(params, cfg: ModelConfig, rc: RunConfig, rules, batch,
             x, a, cache = _mixer_block_seq(cfg, rc, rules, p, x, positions,
                                            enc_out)
             aux = aux + a.get("moe_aux", 0.0)
-            return (x, aux), (cache if want_cache else 0)
+            # per-layer counters of a dropless expert layer (none else)
+            stats = {k: v for k, v in a.items() if k != "moe_aux"}
+            return (x, aux), (cache if want_cache else 0, stats)
 
-        (x, moe_aux), caches = jax.lax.scan(
-            _remat(body, rc.remat_policy), (x, jnp.zeros((), jnp.float32)),
-            params["blocks"])
+        carry = (x, jnp.zeros((), jnp.float32))
+        if "dense_blocks" in params:
+            carry, _ = jax.lax.scan(_remat(body, rc.remat_policy), carry,
+                                    params["dense_blocks"])
+        (x, moe_aux), (caches, stats) = jax.lax.scan(
+            _remat(body, rc.remat_policy), carry, params["blocks"])
         aux_acc["moe_aux"] = moe_aux
+        if stats:
+            # assignments to held experts summed over the expert layers;
+            # the fullest held expert's load, worst layer
+            aux_acc["moe_tokens_held"] = stats["tokens_held"].sum()
+            aux_acc["moe_max_load"] = stats["max_load"].max()
 
     x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
     return x, aux_acc, (caches if want_cache else None)
@@ -334,9 +361,8 @@ def forward_loss(params, cfg, rc, rules, batch):
                                       valid_vocab=cfg.vocab_size)
     loss = tot / jnp.maximum(cnt, 1.0)
     if cfg.moe is not None:
-        loss = loss + 0.01 * aux["moe_aux"] / cfg.n_layers
-    return loss, {"xent": tot / jnp.maximum(cnt, 1.0),
-                  "moe_aux": aux["moe_aux"]}
+        loss = loss + moe_mod.objective(cfg, aux["moe_aux"])
+    return loss, {"xent": tot / jnp.maximum(cnt, 1.0), **aux}
 
 
 # ==========================================================================
@@ -348,11 +374,20 @@ def _kv_capacity(cfg: ModelConfig, seq_len: int) -> int:
     return min(cfg.sliding_window, seq_len) if cfg.sliding_window else seq_len
 
 
+def no_mla_serving(cfg: ModelConfig) -> None:
+    """Prefill and decode through a latent (MLA) cache are not built."""
+    if cfg.kv_lora_rank:
+        raise NotImplementedError(
+            f"{cfg.arch_id}: serving (prefill / decode) is not built for "
+            "multi-head latent attention; this family trains only")
+
+
 def init_decode_state(cfg: ModelConfig, shape: ShapeConfig, rc: RunConfig):
     """Zero-initialized decode caches for a (arch, shape) cell.
 
     Layout is (L, B, ...) — layer-stacked for the decode layer scan.
     """
+    no_mla_serving(cfg)
     B = shape.global_batch
     T = _kv_capacity(cfg, shape.seq_len)
     dt = jnp.dtype(rc.dtype)
@@ -443,10 +478,7 @@ def _decode_mixer_block(cfg, rc, rules, p, x, lcache, pos):
         x = x + attn.out_proj(p["xattn"], ox)
     h2 = L.rms_norm(x, p["ln2"], cfg.norm_eps)
     if "moe" in p:
-        y, _ = moe_mod.moe_apply(
-            p["moe"], h2, num_experts=cfg.moe.num_experts, top_k=cfg.moe.top_k,
-            split=moe_split(cfg), capacity_factor=cfg.moe.capacity_factor,
-            rules=rules)
+        y, _ = moe_mod.apply_layer(p["moe"], h2, cfg, moe_split(cfg), rules)
     else:
         y = L.mlp_apply(p["mlp"], h2)
     return x + y, new_cache
@@ -470,6 +502,7 @@ def _decode_rwkv_block(cfg, rc, p, x, lcache):
 
 def decode_step(params, cfg: ModelConfig, rc: RunConfig, rules, state, token):
     """One decode step. token: (B,1) int32 -> (logits (B,1,V), new state)."""
+    no_mla_serving(cfg)
     dtype = jnp.dtype(rc.dtype)
     x = L.embed_apply(params["embed"], token, dtype)
     pos = state["pos"]
@@ -528,6 +561,7 @@ def decode_step(params, cfg: ModelConfig, rc: RunConfig, rules, state, token):
 
 def prefill(params, cfg: ModelConfig, rc: RunConfig, rules, batch):
     """Process a full prompt; return (last-token logits, decode state)."""
+    no_mla_serving(cfg)
     x, _, caches = forward(params, cfg, rc, rules, batch, want_cache=True)
     head = L.head_matrix(params["embed"])
     logits = jnp.einsum("bd,dv->bv", x[:, -1], head.astype(x.dtype))

@@ -27,6 +27,9 @@ if TYPE_CHECKING:  # annotations only — jax itself is imported lazily
 # "ffn"     -> tensor-parallel (model)
 # "expert"  -> expert-parallel (model) in ep mode, else unsharded
 # "d_inner" -> tensor-parallel (model)  (mamba inner channels)
+# "expert_held" -> unsharded: the experts one chip holds of an expert-
+#              parallel layer are already its share (no exchange here)
+# "kv_latent"-> unsharded: MLA's compressed kv, shared by every head
 # "layers"  -> unsharded for params; ZeRO-1 shards it for optimizer state
 # "seq"     -> sequence-parallel (model) when SP is enabled; else unsharded
 # None      -> replicated
@@ -75,6 +78,8 @@ class ShardingRules:
             "d_inner": model,
             "expert": model if moe_mode == "ep" else None,
             "expert_ffn": model if moe_mode == "tp" else None,
+            "expert_held": None,
+            "kv_latent": None,
             "seq": model if seq_shard else None,
             "cache_time": model if kv_time_shard else None,
             "layers": None,

@@ -1,5 +1,6 @@
 """MANARuntime end-to-end: bit-identical resume, preemption triggers,
 checkpoint cadence, data-pipeline determinism."""
+import jax
 import numpy as np
 import pytest
 
@@ -52,6 +53,42 @@ def test_async_pipeline_resume(tmp_path):
     assert rt2.restore(4) == 4
     hist2 = rt2.run(2)
     assert [h["loss"] for h in hist][4:6] == [h["loss"] for h in hist2]
+
+
+def _donating(monkeypatch):
+    """`LowerHalf.build` as on an accelerator, where the step takes its
+    input state's buffers for its output (on the CPU it does not)."""
+    from repro.core.split_state import LowerHalf
+    build = LowerHalf.build.__func__
+
+    def on_chip(cls, *a, **kw):
+        with monkeypatch.context() as m:
+            m.setattr(jax, "default_backend", lambda: "tpu")
+            return build(cls, *a, **kw)
+
+    monkeypatch.setattr(LowerHalf, "build", classmethod(on_chip))
+
+
+@pytest.mark.parametrize("arch,async_ckpt", [("qwen2-0.5b", False),
+                                             ("qwen2-0.5b", True),
+                                             ("moonlight-16b-a3b", False)])
+def test_resume_with_the_state_donated(tmp_path, monkeypatch, arch,
+                                       async_ckpt):
+    """A donated state is gone after its step, so the safe point's
+    snapshot must be a finished host copy: the image restores and the
+    run resumes bit-identically."""
+    _donating(monkeypatch)
+    cfg = reduced_config(ARCHS[arch])
+    rc = _rc(cfg)
+    rt = MANARuntime(cfg, rc, ckpt_dir=str(tmp_path), ckpt_every_steps=4,
+                     async_ckpt=async_ckpt)
+    rt.initialize()
+    first = rt.state
+    hist = rt.run(6)
+    assert all(x.is_deleted() for x in jax.tree.leaves(first))
+    rt2 = MANARuntime(cfg, rc, ckpt_dir=str(tmp_path))
+    assert rt2.restore(4) == 4
+    assert [h["loss"] for h in rt2.run(2)] == [h["loss"] for h in hist][4:6]
 
 
 def test_resume_wrong_arch_rejected(tmp_path):
